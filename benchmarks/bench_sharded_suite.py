@@ -3,18 +3,21 @@ sweep (core/plan.py Placement, DESIGN.md §11).
 
 Runs the two placement-sensitive suites — ``suites/apps.json`` (Table 5
 appdb proxies, many near-singleton buckets) and ``suites/widelane.json``
-(few patterns, huge counts) — inside a subprocess that forces ``N_DEV``
-fake host devices (XLA_FLAGS must be set before jax initializes, so this
-cannot run in the parent process).  Each suite runs single-device and at
-every mesh shape in ``SHAPES`` (``8x1``, ``4x2``, ``2x4``, ``1x8``);
-per shape we record aggregate harmonic-mean GB/s, wall clock, exact
-compile count, and the plan's pad waste at that ``(batch, lane)`` grid —
-the number the 2-D layer exists to shrink (a ``8x1`` launch of a
+(few patterns, huge counts) — on the host's accelerators when it has
+several, else inside a subprocess pinned to the CPU that forces
+``N_DEV`` fake host devices (XLA_FLAGS must be set before jax
+initializes, so this cannot run in the parent process).  Each suite runs
+single-device and at every pow-2 mesh shape of the device count
+(``8x1``, ``4x2``, ``2x4``, ``1x8`` on eight); per shape we record
+aggregate harmonic-mean GB/s, wall clock, exact compile count, and the
+plan's pad waste at that ``(batch, lane)`` grid — the number the 2-D
+layer exists to shrink (a ``8x1`` launch of a
 2-member bucket wastes 6/8 of the mesh on scratch patterns; ``4x2``
 moves half of that parallelism onto the lane axis).
 
 The per-shape records merge into ``BENCH_suite.json`` (key
-``mesh_sweep``) so the shape trajectory rides the canonical perf record.
+``mesh_sweep``, labelled with the device it ran on) so the shape
+trajectory rides the canonical perf record.
 
 On a CPU host the fake devices share the same cores, so wall-clock
 parity (not speedup) is the expected result — the bench verifies the
@@ -31,25 +34,43 @@ import textwrap
 
 from .harness import emit
 
-N_DEV = 8
-SHAPES = ((8, 1), (4, 2), (2, 4), (1, 8))
+N_DEV = 8                       # fake CPU devices when no chips are present
 SUITES = ("apps", "widelane")
 OUT_PATH = "BENCH_suite.json"
 
 _CHILD = textwrap.dedent("""\
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%(n_dev)d"
-    import dataclasses, json, sys, time
-    sys.path.insert(0, %(src)r)
-    import jax
-    from repro.core import ExecutorCache, SuitePlan, load_suite, run_suite
+    import json, sys
+    sys.path[:0] = [%(root)r, %(src)r]
+    from benchmarks.bench_sharded_suite import sweep
+    print(json.dumps(sweep(%(root)r, %(runs)d, %(cap)d)))
+    """)
 
-    runs = %(runs)d
-    cap = %(cap)d
-    shapes = %(shapes)r
-    out = {}
-    for name in %(suites)r:
-        pats = load_suite(%(root)r + "/suites/" + name + ".json")
+
+def grid_shapes(n_dev: int) -> tuple:
+    """Every pow-2 (batch, lane) factorization of ``n_dev``, batch-major
+    first: 8 -> 8x1, 4x2, 2x4, 1x8."""
+    out = []
+    b = n_dev
+    while b >= 1:
+        if n_dev % b == 0:
+            out.append((b, n_dev // b))
+        b //= 2
+    return tuple(out)
+
+
+def sweep(root: str, runs: int, cap: int) -> dict:
+    """The mesh-shape sweep on the devices this process sees."""
+    import dataclasses
+    import time
+
+    import jax
+
+    from repro.core import ExecutorCache, SuitePlan, load_suite, run_suite
+    d = jax.devices()[0]
+    out = {"device": {"platform": d.platform, "device_kind": d.device_kind,
+                      "count": len(jax.devices())}}
+    for name in SUITES:
+        pats = load_suite(os.path.join(root, "suites", name + ".json"))
         if cap:
             pats = [dataclasses.replace(p, count=min(p.count, cap))
                     for p in pats]
@@ -64,37 +85,46 @@ _CHILD = textwrap.dedent("""\
                           "compiles": cache.stats().misses,
                           "pad_waste": plan.pad_waste()},
                "shapes": {}}
-        for b, l in shapes:
+        for b, l in grid_shapes(len(jax.devices())):
             cache = ExecutorCache()
             t0 = time.perf_counter()
             stats = run_suite(pats, backend="xla", runs=runs, cache=cache,
                               mesh=(b, l))
-            rec["shapes"]["%%dx%%d" %% (b, l)] = {
+            rec["shapes"][f"{b}x{l}"] = {
                 "hmean_gbs": stats.hmean_gbs,
                 "wall_s": time.perf_counter() - t0,
                 "compiles": cache.stats().misses,
                 "pad_waste": plan.pad_waste(b, l),
             }
         out[name] = rec
-    print(json.dumps(out))
-    """)
+    return out
 
 
 def run(runs: int = 3, *, out_path: str | None = OUT_PATH) -> dict:
+    """Sweep on the host's accelerators when there are several; else in
+    a child pinned to the CPU with ``N_DEV`` fake devices (its record
+    says ``platform: cpu``) — the parent may hold a chip, and the forced
+    device count must never leak into it."""
+    import jax
     root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
     cap = 2048 if runs <= 3 else 0          # quick mode: count cap
-    code = _CHILD % {
-        "n_dev": N_DEV, "src": os.path.join(root, "src"), "root": root,
-        "runs": runs, "cap": cap,
-        "shapes": tuple(SHAPES), "suites": tuple(SUITES),
-    }
-    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, timeout=540)
-    if r.returncode != 0:
-        raise RuntimeError(f"sharded-suite child failed: {r.stderr[-2000:]}")
-    sweep = json.loads(r.stdout.strip().splitlines()[-1])
+    if jax.devices()[0].platform != "cpu" and len(jax.devices()) > 1:
+        swept = sweep(root, runs, cap)
+    else:
+        code = _CHILD % {"root": root, "src": os.path.join(root, "src"),
+                         "runs": runs, "cap": cap}
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   XLA_FLAGS=f"--xla_force_host_platform_device_count="
+                             f"{N_DEV}")
+        r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                           text=True, timeout=540, env=env)
+        if r.returncode != 0:
+            raise RuntimeError(
+                f"sharded-suite child failed: {r.stderr[-2000:]}")
+        swept = json.loads(r.stdout.strip().splitlines()[-1])
+    device = swept.pop("device")
 
-    for name, rec in sweep.items():
+    for name, rec in swept.items():
         emit(f"sharded_suite/{name}_single",
              rec["single"]["wall_s"] * 1e6,
              f"{rec['single']['hmean_gbs']:.2f}GB/s;"
@@ -122,12 +152,12 @@ def run(runs: int = 3, *, out_path: str | None = OUT_PATH) -> dict:
         if os.path.exists(out_path):
             with open(out_path) as f:
                 doc = json.load(f)
-        doc["mesh_sweep"] = {"n_dev": N_DEV, "runs": runs,
-                             "count_cap": cap, "suites": sweep}
+        doc["mesh_sweep"] = {"device": device, "runs": runs,
+                             "count_cap": cap, "suites": swept}
         with open(out_path, "w") as f:
             json.dump(doc, f, indent=2)
         emit("sharded_suite/json", 0.0, out_path)
-    return sweep
+    return swept
 
 
 if __name__ == "__main__":

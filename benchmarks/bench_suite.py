@@ -7,10 +7,10 @@ counts (ExecutorCache.misses — exact) and the pallas one-launch-per-bucket
 census (pallas_call primitives in each store/gather bucket executable's
 jaxpr).  Two §16 sections ride along: ``autotune`` (the pallas sweep
 under the legacy fixed tiles vs the deterministic tile search, plus the
-per-geometry tiles chosen) and ``pallas_lane`` (lane-sharded pallas on 8
-fake devices in a child process, bit-identity checked against the
-single-device planner).  The file is read-merge-written — other benches
-own their own sections.  CI uploads it as an artifact so the perf
+per-geometry tiles chosen) and ``pallas_lane`` (lane-sharded pallas on
+the host's accelerators, or on 8 fake CPU devices in a child process,
+bit-identity checked against the single-device planner).  The file is
+read-merge-written — other benches own their own sections.  CI uploads it as an artifact so the perf
 trajectory is tracked across PRs; compare against the committed baseline
 with::
 
@@ -45,52 +45,73 @@ DEFAULT_SUITE = "suites/demo.json"
 DEFAULT_OUT = "BENCH_suite.json"
 BACKENDS = ("xla", "onehot", "scalar", "pallas")
 
-# §16 lane-sharded pallas sweep: its own process so the forced device
-# count never leaks into this one (same discipline as the sharded bench)
-LANE_SHAPES = ((1, 8), (4, 2))
+# §16 lane-sharded pallas sweep: on the host's own accelerators when it
+# has several, else in a child process pinned to the CPU with 8 fake
+# devices (the forced device count must never leak into this process,
+# and a child must never contend for a chip this process holds)
+FAKE_DEVICES = 8
 _LANE_CHILD = textwrap.dedent("""\
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    import dataclasses, json, sys, time
-    sys.path.insert(0, %(src)r)
-    from repro.core import ExecutorCache, load_suite, run_suite
+    import json, sys
+    sys.path[:0] = [%(root)r, %(src)r]
+    from benchmarks.bench_suite import _lane_rows
+    print(json.dumps(_lane_rows(%(suite)r, %(runs)d, %(cap)d)))
+    """)
 
-    pats = load_suite(%(suite)r)
-    cap = %(cap)d
+
+def lane_shapes(n_dev: int) -> tuple:
+    """Lane-sharded placements over ``n_dev`` devices: all lanes, and
+    half the devices on the batch axis when there are at least four."""
+    return ((1, n_dev),) + (((n_dev // 2, 2),) if n_dev >= 4 else ())
+
+
+def device_label() -> dict:
+    d = jax.devices()[0]
+    return {"platform": d.platform, "device_kind": d.device_kind,
+            "count": len(jax.devices())}
+
+
+def _lane_rows(suite: str, runs: int, cap: int) -> dict:
+    """hmean per lane-sharded shape + bit-identity vs single device, on
+    the devices this process sees."""
+    pats = load_suite(suite)
     if cap:
         pats = [dataclasses.replace(p, count=min(p.count, cap))
                 for p in pats]
-    out = {}
-    ref = run_suite(pats, backend="pallas", runs=%(runs)d,
+    out = {"device": device_label()}
+    ref = run_suite(pats, backend="pallas", runs=runs,
                     cache=ExecutorCache(), digest=True)
     d_ref = [r.out_digest for r in ref.results]
     out["single"] = {"hmean_gbs": ref.hmean_gbs}
-    for b, l in %(shapes)r:
+    for b, l in lane_shapes(len(jax.devices())):
         cache = ExecutorCache()
         t0 = time.perf_counter()
-        stats = run_suite(pats, backend="pallas", runs=%(runs)d,
+        stats = run_suite(pats, backend="pallas", runs=runs,
                           cache=cache, mesh=(b, l), digest=True)
-        out["%%dx%%d" %% (b, l)] = {
+        out[f"{b}x{l}"] = {
             "hmean_gbs": stats.hmean_gbs,
             "wall_s": time.perf_counter() - t0,
             "compiles": cache.stats().misses,
             "digests_match_single":
                 [r.out_digest for r in stats.results] == d_ref,
         }
-    print(json.dumps(out))
-    """)
+    return out
 
 
 def _pallas_lane_sweep(suite: str, runs: int, cap: int) -> dict:
-    """Lane-sharded pallas on 8 fake devices: hmean per shape + the
-    bit-identity check against the single-device planner."""
-    src = os.path.abspath(os.path.join(os.path.dirname(__file__),
-                                       "..", "src"))
-    code = _LANE_CHILD % {"src": src, "suite": os.path.abspath(suite),
-                          "cap": cap, "runs": runs,
-                          "shapes": tuple(LANE_SHAPES)}
+    """Lane-sharded pallas: in-process on the host's accelerators when
+    there are several, else on 8 fake CPU devices in a child (its record
+    says ``platform: cpu``)."""
+    if jax.devices()[0].platform != "cpu" and len(jax.devices()) > 1:
+        return _lane_rows(suite, runs, cap)
+    root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+    code = _LANE_CHILD % {"root": root, "src": os.path.join(root, "src"),
+                          "suite": os.path.abspath(suite), "cap": cap,
+                          "runs": runs}
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count="
+                         f"{FAKE_DEVICES}")
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, timeout=540)
+                       text=True, timeout=540, env=env)
     if r.returncode != 0:
         raise RuntimeError(f"pallas-lane child failed: {r.stderr[-2000:]}")
     return json.loads(r.stdout.strip().splitlines()[-1])
@@ -177,7 +198,7 @@ def run(runs: int = 3, *, suite: str = DEFAULT_SUITE,
         lane = _pallas_lane_sweep(suite, max(1, min(runs, 2)),
                                   min(count_cap or 128, 128))
         for shape, row in lane.items():
-            if shape == "single":
+            if shape in ("single", "device"):
                 continue
             emit(f"suite/pallas_lane_{shape}", row["wall_s"] * 1e6,
                  f"{row['hmean_gbs']:.3e}gbs;"
@@ -192,6 +213,8 @@ def run(runs: int = 3, *, suite: str = DEFAULT_SUITE,
             "n_buckets": plan.n_buckets,
             "jax": jax.__version__,
             "device": jax.devices()[0].platform,
+            "device_kind": jax.devices()[0].device_kind,
+            "device_count": len(jax.devices()),
             "host": platform.machine(),
         },
         "backends": per_backend,
@@ -206,8 +229,9 @@ def run(runs: int = 3, *, suite: str = DEFAULT_SUITE,
                 per_backend.get("pallas", {}).get("hmean_measured_gbs"),
             "tiles": autotune.to_wire(tiles),
         },
-        # §16: lane-sharded pallas (8 fake devices, own process) — every
-        # shape must stay bit-identical to the single-device planner
+        # §16: lane-sharded pallas (the host's accelerators, or 8 fake CPU
+        # devices in a child — see its "device" label) — every shape must
+        # stay bit-identical to the single-device planner
         "pallas_lane": lane,
     }
     if out_path:                       # None = CSV only, no trajectory write

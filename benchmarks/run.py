@@ -31,6 +31,9 @@ def main() -> None:
     args = ap.parse_args()
     runs = 3 if args.quick else 5
 
+    from repro.core.compile_cache import use_compile_cache
+    use_compile_cache()
+
     from . import (bench_app_patterns, bench_llm_gs, bench_prefetch,
                    bench_roofline, bench_serve, bench_sharded_suite,
                    bench_stream, bench_suite, bench_suite_scaling,

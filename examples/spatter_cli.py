@@ -318,6 +318,8 @@ def main():
         ap.error("--stream-r only applies to --json suite mode")
     from repro.core import GSEngine, Placement, load_suite, make_pattern, \
         run_suite
+    from repro.core.compile_cache import use_compile_cache
+    use_compile_cache()
 
     mesh = None
     mesh_grid = (1, 1)

@@ -301,12 +301,12 @@ def _sharding_consistency(unit: ExecUnit) -> list[Violation]:
             location=f"shardings seen: {sorted(stats['shardings'])[:4]}",
             message=(f"placement {unit.key.placement!r} promises tile "
                      f"{tile} but no lowered operand sharding carries it")))
-    # the §16 manual path: a lane-sharded pallas executable splits its
-    # axes with shard_map, and every shard_map's mesh must be exactly
-    # the named axes the placement string promises — a drifted mesh
-    # (wrong split, renamed axis, stale grid) would still lower and run,
-    # just on the wrong decomposition
-    if unit.key.backend == "pallas" and l > 1:
+    # the §16 manual path: a placed pallas executable splits its axes
+    # with shard_map, and every shard_map's mesh must be exactly the
+    # named axes the placement string promises — a drifted mesh (wrong
+    # split, renamed axis, stale grid) would still lower and run, just
+    # on the wrong decomposition
+    if unit.key.backend == "pallas":
         from repro.core.plan import placement_axes
         from repro.core.tracing import shard_map_meshes
         want = placement_axes(unit.key.placement)
@@ -314,10 +314,10 @@ def _sharding_consistency(unit: ExecUnit) -> list[Violation]:
         if not meshes:
             out.append(Violation(
                 rule="sharding-spec-consistency", exec_key=unit.label,
-                message=(f"lane-sharded pallas key "
+                message=(f"placed pallas key "
                          f"{unit.key.placement!r} but the jaxpr has no "
                          f"shard_map — the launch relies on GSPMD "
-                         f"replication, not the §16 manual lane split")))
+                         f"replication, not the §16 manual split")))
         for got in meshes:
             live = {k: v for k, v in got.items() if v > 1}
             if live != want:

@@ -57,13 +57,27 @@ def gather_xla(src: jax.Array, idx: jax.Array) -> jax.Array:
     return jnp.take(src, idx, axis=0)
 
 
+def _select_rows(oh: jax.Array, x: jax.Array) -> jax.Array:
+    """``oh @ x`` for a one-hot ``oh`` with at most one 1 per row, bit for
+    bit: rows move as 32-bit words, byte by byte
+    (``scatter_rows.kernel.select_words``), so -0.0, inf and NaN payloads
+    survive where a float contraction would not."""
+    from repro.kernels.scatter_rows.kernel import select_words
+    word = x.dtype if x.dtype.itemsize == 4 else jnp.float32
+    words = lax.bitcast_convert_type(x.astype(word), jnp.int32)
+    ohb = oh.astype(jnp.bfloat16)
+    out = select_words(lambda b: jnp.matmul(
+        ohb, b.astype(jnp.bfloat16), preferred_element_type=jnp.float32),
+        words)
+    return lax.bitcast_convert_type(out, word).astype(x.dtype)
+
+
 def gather_onehot(src: jax.Array, idx: jax.Array) -> jax.Array:
     """Gather as one-hot matmul: out = onehot(idx) @ src.  MXU-resident on TPU."""
     f = src.shape[0]
     if f > _ONEHOT_MAX_FOOTPRINT:
         raise ValueError(f"onehot backend: footprint {f} too large")
-    oh = jax.nn.one_hot(idx, f, dtype=src.dtype)
-    return oh @ src
+    return _select_rows(jax.nn.one_hot(idx, f, dtype=jnp.bfloat16), src)
 
 
 def gather_scalar(src: jax.Array, idx: jax.Array) -> jax.Array:
@@ -150,12 +164,14 @@ def scatter_onehot(dst: jax.Array, idx: jax.Array, vals: jax.Array,
     if f > _ONEHOT_MAX_FOOTPRINT:
         raise ValueError(f"onehot backend: footprint {f} too large")
     if mode == "add":
+        # HIGHEST: the chip's DEFAULT f32 dot is one bf16 pass
         oh = jax.nn.one_hot(idx, f, dtype=vals.dtype)      # (N, F)
-        return dst + oh.T @ vals
+        return dst + jnp.matmul(oh.T, vals, precision=lax.Precision.HIGHEST)
     keep = _store_keep(keep, idx, f)
-    oh = jax.nn.one_hot(idx, f, dtype=vals.dtype) * keep[:, None].astype(vals.dtype)
-    covered = jnp.clip(oh.sum(axis=0), 0, 1)[:, None]      # (F, 1) in {0,1}
-    return dst * (1 - covered) + oh.T @ vals
+    # dropped lanes go out of range: an all-zero one-hot row
+    oh = jax.nn.one_hot(jnp.where(keep, idx, f), f, dtype=jnp.bfloat16)
+    covered = jnp.any(oh > 0, axis=0)[:, None]               # (F, 1)
+    return jnp.where(covered, _select_rows(oh.T, vals), dst)
 
 
 def scatter_scalar(dst: jax.Array, idx: jax.Array, vals: jax.Array,

@@ -1,31 +1,21 @@
-"""JAX version-compatibility shims (compat policy).
+"""The JAX mesh and manual-sharding API the repo is written against (0.9).
 
-The repo pins no jax version; the container ships jax 0.4.37 but the code
-must keep working as the APIs it touches migrate.  Policy: every
-cross-version API goes through ONE symbol defined here — call sites never
-feature-test jax themselves.  Current shims:
+Policy: every mesh the program builds, and every manual shard_map body,
+goes through ONE symbol defined here, so a future API move is a change
+to this file alone.
 
-``shard_map``
-    Lived in ``jax.experimental.shard_map`` through the 0.4/0.5 series and
-    was promoted to ``jax.shard_map`` in newer releases.  We prefer the
-    top-level export when present and fall back to the experimental module.
+``make_mesh(shape, names)`` / ``make_abstract_mesh(shape, names)``
+    JAX 0.9's ``jax.make_mesh`` defaults to ``AxisType.Explicit``, under
+    which a jitted ``pallas_call`` or a sharded ``dynamic_update_slice``
+    is refused unless every operand's sharding is spelled out.  The
+    planner's GSPMD paths rely on the compiler propagating shardings, so
+    every mesh declares its axes ``Auto``.
 
-``make_abstract_mesh(shape, names)``
-    ``jax.sharding.AbstractMesh`` changed constructors: old releases
-    (including 0.4.37) take a single ``shape_tuple`` of ``(name, size)``
-    pairs; newer ones take ``(axis_sizes, axis_names)`` positionally.
-    This helper accepts the uniform ``(sizes, names)`` form and builds the
-    mesh whichever way the installed jax understands.
-
-``axis_size(name)``
-    ``jax.lax.axis_size`` is a late addition; on older jax the idiom is
-    ``lax.psum(1, name)``, which evaluates statically to a Python int.
-
-``pcast(x, name, to=...)``
-    ``jax.lax.pcast`` belongs to the newer varying-manual-axes (VMA) type
-    system.  Older shard_map tracks replication with its own checker and
-    inserts the equivalent coercions itself, so the shim is the identity
-    there.
+``shard_map_unchecked(f, **kw)``
+    ``jax.shard_map`` with the varying-manual-axes checker off, for
+    bodies that thread rank-local state through a nominally replicated
+    out_spec (error-feedback residuals) or combine shards by hand (the
+    lane-sharded Pallas launch).
 """
 from __future__ import annotations
 
@@ -33,60 +23,34 @@ from typing import Sequence
 
 import jax
 from jax import lax
+from jax.sharding import AxisType
 
-if hasattr(jax, "shard_map"):           # newer jax: top-level export
-    shard_map = jax.shard_map
-else:                                   # jax <= 0.5: experimental module
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+shard_map = jax.shard_map
+axis_size = lax.axis_size
+pcast = lax.pcast
 
 
 def shard_map_unchecked(f, **kw):
-    """shard_map with the replication/VMA checker off.
-
-    For bodies that thread rank-local state (e.g. error-feedback residuals)
-    through a nominally-replicated out_spec: every checker generation
-    rejects that, but the per-device buffers carry the state correctly as
-    long as nothing reshards them.  The disable flag was renamed
-    ``check_rep`` -> ``check_vma`` across jax versions; pass whichever the
-    installed shard_map accepts.
-    """
-    import inspect
-    params = inspect.signature(shard_map).parameters
-    if "check_vma" in params:
-        return shard_map(f, check_vma=False, **kw)
-    return shard_map(f, check_rep=False, **kw)
+    """``jax.shard_map`` with the VMA checker off."""
+    return jax.shard_map(f, check_vma=False, **kw)
 
 
-def axis_size(name: str) -> int:
-    """Static size of a mapped mesh axis, on any supported jax version."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(name)
-    return lax.psum(1, name)
+def _auto(names: Sequence[str]) -> tuple:
+    return (AxisType.Auto,) * len(names)
 
 
-def pcast(x, name: str, *, to: str = "varying"):
-    """Coerce replicated<->varying under shard_map where jax supports it."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, name, to=to)
-    if to == "varying" and hasattr(lax, "pvary"):
-        # the VMA window before pcast existed: pvary is the varying cast,
-        # and skipping it there silently drops replicated-input gradients
-        return lax.pvary(x, name)
-    return x
+def make_mesh(shape: Sequence[int], names: Sequence[str], *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto`` (see module doc)."""
+    if len(shape) != len(names):
+        raise ValueError(f"shape/names length mismatch: {shape} vs {names}")
+    return jax.make_mesh(tuple(int(s) for s in shape), tuple(names),
+                         axis_types=_auto(names), devices=devices)
 
 
 def make_abstract_mesh(shape: Sequence[int], names: Sequence[str]):
-    """Build ``jax.sharding.AbstractMesh`` on any supported jax version.
-
-    ``shape`` are the axis sizes and ``names`` the axis names, e.g.
-    ``make_abstract_mesh((16, 16), ("data", "model"))``.
-    """
+    """``jax.sharding.AbstractMesh`` with every axis ``Auto``, e.g.
+    ``make_abstract_mesh((16, 16), ("data", "model"))``."""
     if len(shape) != len(names):
         raise ValueError(f"shape/names length mismatch: {shape} vs {names}")
-    AbstractMesh = jax.sharding.AbstractMesh
-    try:
-        # newer jax: AbstractMesh(axis_sizes, axis_names)
-        return AbstractMesh(tuple(int(s) for s in shape), tuple(names))
-    except TypeError:
-        # jax <= 0.4.x: AbstractMesh(shape_tuple of (name, size) pairs)
-        return AbstractMesh(tuple((n, int(s)) for n, s in zip(names, shape)))
+    return jax.sharding.AbstractMesh(tuple(int(s) for s in shape),
+                                     tuple(names), axis_types=_auto(names))

@@ -169,21 +169,42 @@ def main_io_bytes(text: str) -> dict:
 # --------------------------------------------------------------------------
 
 RE_PARTITIONS = re.compile(r"num_partitions\s*=\s*(\d+)")
-RE_SHARDING = re.compile(r'mhlo\.sharding\s*=\s*"([^"]*)"')
+# Shardy annotations: ``sdy.mesh @mesh = <["data"=4, "lane"=2]>`` and, per
+# value, ``#sdy.sharding<@mesh, [{"data"}, {"lane"}]>`` (one {} per dim)
+RE_SDY_MESH = re.compile(r"sdy\.mesh\s+@([\w.]+)\s*=\s*<\[([^\]]*)\]")
+RE_SHARDING = re.compile(r"#sdy\.sharding<@([\w.]+),\s*\[((?:\{[^}]*\}"
+                         r"(?:,\s*)?)*)\]")
 RE_ALIASING = re.compile(r"tf\.aliasing_output|jax\.buffer_donor")
+
+
+def _tile(mesh: dict, dims: str) -> str:
+    """``devices=[d0,d1,...]``: the shard count of each dim of a Shardy
+    sharding — the same tile spelling a GSPMD annotation uses."""
+    counts = []
+    for dim in re.findall(r"\{([^}]*)\}", dims):
+        n = 1
+        for ax in re.findall(r'"([^"]+)"', dim):
+            n *= mesh.get(ax, 1)
+        counts.append(str(n))
+    return f"devices=[{','.join(counts)}]"
 
 
 def hlo_stats(text: str) -> dict:
     """Structured census of a lowered module's text
     (``fn.lower(*avals).as_text()``).
 
-    Returns ``num_partitions`` (1 when unpartitioned), the set of
-    ``mhlo.sharding`` attribute strings, and ``aliased_params`` — the
-    number of input/output aliasing (donation) markers.
+    Returns ``num_partitions`` (1 when unpartitioned), the set of value
+    shardings as ``devices=[...]`` tiles (shard count per dim, from the
+    Shardy annotations), and ``aliased_params`` — the number of
+    input/output aliasing (donation) markers.
     """
     m = RE_PARTITIONS.search(text)
+    meshes = {name: {ax: int(n) for ax, n in
+                     re.findall(r'"([^"]+)"\s*=\s*(\d+)', body)}
+              for name, body in RE_SDY_MESH.findall(text)}
     return {
         "num_partitions": int(m.group(1)) if m else 1,
-        "shardings": set(RE_SHARDING.findall(text)),
+        "shardings": {_tile(meshes.get(name, {}), dims)
+                      for name, dims in RE_SHARDING.findall(text)},
         "aliased_params": len(RE_ALIASING.findall(text)),
     }

@@ -656,10 +656,10 @@ def _lane_body(kind: str, mode: str, lane_axis: str) -> Callable:
         ~1-ulp tolerance).
       * scatter-store: the host keep mask deduped writes BEFORE the lane
         split, so globally at most one shard writes each row.  The store
-        kernel's ``with_covered`` output says which rows this shard
+        kernel's ``with_covered`` output says which elements this shard
         wrote; psum of disjoint contributions is an exact select, and
-        uncovered rows keep ``dst`` — bit-identical to the single-device
-        launch.
+        uncovered elements keep ``dst`` — bit-identical to the
+        single-device launch.
 
     The signature mirrors ``_raw_batched_fn`` exactly, so the lane path
     launches with the same operand list as every other placement.
@@ -682,8 +682,8 @@ def _lane_body(kind: str, mode: str, lane_axis: str) -> Callable:
             out_l, cov_l = scatter_ops.scatter_store_rows_batched(
                 jnp.zeros_like(dst_b), safe, vals_b, with_covered=True)
             covered = jax.lax.psum(cov_l, lane_axis)
-            return jnp.where(covered[..., None] > 0,
-                             jax.lax.psum(out_l, lane_axis), dst_b)
+            return jnp.where(covered > 0, jax.lax.psum(out_l, lane_axis),
+                             dst_b)
     return fn
 
 
@@ -740,6 +740,7 @@ class Placement:
             raise ValueError(f"placement shape must be N or (b, l) with "
                              f"b, l >= 1, got {shape}")
         b, l = shape[0], shape[1] if len(shape) == 2 else 1
+        from .compat import make_mesh
         n_dev = len(jax.devices())
         if b * l > n_dev:
             raise ValueError(
@@ -747,12 +748,12 @@ class Placement:
                 f"(set XLA_FLAGS=--xla_force_host_platform_device_count="
                 f"{b * l} to fake devices on CPU)")
         if l == 1:
-            return Placement(jax.make_mesh((b,), (batch_axis,)),
+            return Placement(make_mesh((b,), (batch_axis,)),
                              batch_axis=batch_axis, lane_axis=None)
         if b == 1:
-            return Placement(jax.make_mesh((l,), (lane_axis,)),
+            return Placement(make_mesh((l,), (lane_axis,)),
                              batch_axis=None, lane_axis=lane_axis)
-        return Placement(jax.make_mesh((b, l), (batch_axis, lane_axis)),
+        return Placement(make_mesh((b, l), (batch_axis, lane_axis)),
                          batch_axis=batch_axis, lane_axis=lane_axis)
 
     @staticmethod
@@ -800,31 +801,33 @@ class Placement:
     def build(self, backend: str, kind: str, mode: str) -> Callable:
         """Jit the batched bucket op with this placement's shardings.
 
-        A pallas launch with a non-degenerate lane axis routes through
+        Every pallas placement routes through
         ``compat.shard_map_unchecked`` (DESIGN.md §16): GSPMD has no
-        partitioning rule for ``pallas_call``, so the GSPMD jit path
-        would all-gather the lane shards and run the kernel replicated —
-        the manual shard_map body instead runs the kernel on each
-        device's lane shard and combines explicitly
-        (``_lane_body``).  Every other placement keeps the plain GSPMD
-        jit (XLA partitions its ops natively; batch-only pallas shards
-        cleanly along the grid's leading dim).
+        partitioning rule for ``pallas_call``, so under a plain jit the
+        kernel would run replicated behind all-gathers of its operands.
+        The manual body runs the kernel on each device's own shard — its
+        patterns (batch axis) and its lanes (lane axis) — and, with a
+        lane split, combines across the lane axis explicitly
+        (``_lane_body``).  The other backends keep the plain GSPMD jit:
+        XLA partitions their ops natively.
         """
         in_sh, out_sh = self.shardings(kind)
-        if (backend == "pallas" and self.lane_axis is not None
-                and self.lane_shards > 1):
-            from repro.runtime.sharding import gs_specs
+        if backend != "pallas":
+            return jax.jit(_raw_batched_fn(backend, kind, mode),
+                           in_shardings=in_sh, out_shardings=out_sh)
+        from repro.runtime.sharding import gs_specs
 
-            from . import compat
-            in_specs, out_spec = gs_specs(kind, batched=True,
-                                          batch_axis=self.batch_axis,
-                                          lane_axis=self.lane_axis)
-            body = compat.shard_map_unchecked(
-                _lane_body(kind, mode, self.lane_axis), mesh=self.mesh,
-                in_specs=tuple(in_specs), out_specs=out_spec)
-            return jax.jit(body, in_shardings=in_sh, out_shardings=out_sh)
-        return jax.jit(_raw_batched_fn(backend, kind, mode),
-                       in_shardings=in_sh, out_shardings=out_sh)
+        from . import compat
+        in_specs, out_spec = gs_specs(kind, batched=True,
+                                      batch_axis=self.batch_axis,
+                                      lane_axis=self.lane_axis)
+        body = (_lane_body(kind, mode, self.lane_axis)
+                if self.lane_shards > 1
+                else _raw_batched_fn(backend, kind, mode))
+        body = compat.shard_map_unchecked(
+            body, mesh=self.mesh, in_specs=tuple(in_specs),
+            out_specs=out_spec)
+        return jax.jit(body, in_shardings=in_sh, out_shardings=out_sh)
 
     def place(self, kind: str, args: tuple) -> tuple:
         """Commit assembled host buffers to their launch shardings.

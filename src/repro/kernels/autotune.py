@@ -48,7 +48,7 @@ class TileKey:
     shard dims, so an 8-way lane split of a 4Ki-lane bucket tunes for
     512 lanes, not 4Ki.
     """
-    op: str             # "gather_vmem" | "gather_dma" | "scatter"
+    op: str             # "gather" | "scatter"
     batch: int          # pattern-batch dim
     lanes: int          # index lanes per pattern (pre block padding)
     rows: int           # table / destination rows (incl. scratch row)
@@ -60,25 +60,27 @@ class TileKey:
 @dataclasses.dataclass(frozen=True)
 class TileChoice:
     """A concrete tiling; fields irrelevant to the op stay 0."""
-    block_n: int = 0    # gather_vmem lanes per step / scatter chunk lanes
-    block_v: int = 0    # scatter output-tile rows
-    block_i: int = 0    # gather_dma rows per DMA block
-    block_d: int = 0    # gather_dma row-slice width
+    block: int = 0      # indices per grid step (gather) / chunk (scatter)
+    block_v: int = 0    # scatter output-tile rows (lines when narrow)
 
     def to_wire(self) -> list[int]:
-        return [self.block_n, self.block_v, self.block_i, self.block_d]
+        return [self.block, self.block_v]
 
     @staticmethod
     def from_wire(v) -> "TileChoice":
-        bn, bv, bi, bd = (int(x) for x in v)
-        return TileChoice(block_n=bn, block_v=bv, block_i=bi, block_d=bd)
+        block, block_v = (int(x) for x in v)
+        return TileChoice(block=block, block_v=block_v)
 
+
+# Index blocks are multiples of this: the SMEM index block's tiling, and
+# 8 sublanes x 128 lanes for the lane-dense VMEM index block.
+INDEX_BLOCK = 1024
+_LANES = 128
 
 # legacy fixed tiles — what the kernels shipped with before the search
 LEGACY = {
-    "gather_vmem": TileChoice(block_n=64),
-    "gather_dma": TileChoice(block_i=8, block_d=512),
-    "scatter": TileChoice(block_n=128, block_v=128),
+    "gather": TileChoice(block=INDEX_BLOCK),
+    "scatter": TileChoice(block=INDEX_BLOCK, block_v=128),
 }
 
 # cost-model constants.  STEP_OVH is the per-grid-step launch overhead in
@@ -87,11 +89,13 @@ LEGACY = {
 # element work and the search minimizes step count; compiled TPU steps
 # are cheap, so tile shape (VMEM fit, MXU occupancy) dominates instead.
 _STEP_OVH = {"interpret": 1 << 17, "tpu": 64}
-# per-candidate feasibility caps, bytes of tile-resident data
+# per-candidate feasibility caps, TILED bytes of tile-resident data
 _TILE_BYTES_CAP = {"interpret": 1 << 26, "tpu": 1 << 21}
-# one-hot membership matrix cap (block_v * block_n elements)
+# one-hot membership matrix cap (block_v * 128 elements per slice)
 _ONEHOT_CAP = {"interpret": 1 << 22, "tpu": 1 << 15}
 _MAX_BLOCK = 4096
+# SMEM holds the index block double-buffered: 8 KiB indices is 64 KiB
+_MAX_INDEX_BLOCK = 8192
 
 
 def _pow2s(lo: int, hi: int):
@@ -117,74 +121,82 @@ def _itemsize(dtype: str) -> int:
     return int(np.dtype(dtype).itemsize)
 
 
-def _search_gather_vmem(key: TileKey) -> TileChoice:
+def tiled_bytes(rows: int, width: int, dtype: str) -> int:
+    """Bytes a (rows, width) array occupies in the chip's tiled layout:
+    the last dim pads to 128 lanes and the one before it to a sublane
+    tile (8 rows of 32-bit; packed dtypes pack more rows per tile)."""
+    item = _itemsize(dtype)
+    sub = 8 * max(1, 4 // item)
+    return (_ceil_div(max(1, rows), sub) * sub
+            * _ceil_div(max(1, width), _LANES) * _LANES * item)
+
+
+def _gather_fetches(key: TileKey) -> int:
+    """Lines or elements the gather kernel fetches per pattern: one per
+    element below a line's width, one per line at or above it."""
+    per_row = (key.width if key.width < _LANES
+               else _ceil_div(key.width, _LANES))
+    return max(1, key.lanes * per_row)
+
+
+def _search_gather(key: TileKey) -> TileChoice:
+    """Indices per grid step for either gather regime.  On the chip a
+    step holds three blocks of ``block`` 128-lane lines (the staging
+    block and the double-buffered output, 512 B per index): 1024
+    indices take 1.5 MiB and 2048 would exceed the tile cap, so one index
+    block is the only fit.  Interpret mode trades steps for block size."""
+    if key.platform == "tpu":
+        return TileChoice(block=INDEX_BLOCK)
     ovh = _STEP_OVH[key.platform]
-    cap = _TILE_BYTES_CAP[key.platform]
-    item = _itemsize(key.dtype)
-    hi = min(_MAX_BLOCK, _next_pow2(key.lanes))
-    best, best_cost = None, None
-    for bn in _pow2s(8, max(8, hi)):
-        # tile residency: the (bn, width) output block, double-buffered
-        if 2 * bn * key.width * item > cap:
-            continue
-        steps = key.batch * _ceil_div(max(1, key.lanes), bn)
-        cost = steps * (ovh + bn * key.width)
+    fetches = _gather_fetches(key)
+    hi = min(_MAX_INDEX_BLOCK, _next_pow2(fetches))
+    best, best_cost = INDEX_BLOCK, None
+    for bn in _pow2s(INDEX_BLOCK, max(INDEX_BLOCK, hi)):
+        cost = key.batch * _ceil_div(fetches, bn) * (ovh + bn)
         if best_cost is None or cost < best_cost:
             best, best_cost = bn, cost
-    return TileChoice(block_n=best if best is not None else 8)
-
-
-def _search_gather_dma(key: TileKey) -> TileChoice:
-    ovh = _STEP_OVH[key.platform]
-    item = _itemsize(key.dtype)
-    # row-slice width: largest pow2 <= min(width, 512) that divides width
-    bd = min(512, _next_pow2(key.width))
-    while bd > 1 and (key.width % bd or 2 * bd * item > _TILE_BYTES_CAP[key.platform]):
-        bd //= 2
-    hi = min(256, _next_pow2(key.lanes))
-    best, best_cost = None, None
-    for bi in _pow2s(8, max(8, hi)):
-        if bi * bd * item * 2 > _TILE_BYTES_CAP[key.platform]:
-            continue
-        steps = key.batch * _ceil_div(max(1, key.lanes), bi)
-        # each step issues bi DMAs of bd elements; overlap hides about
-        # half the copy latency behind the writeback
-        cost = steps * (ovh + bi * (32 + bd))
-        if best_cost is None or cost < best_cost:
-            best, best_cost = bi, cost
-    return TileChoice(block_i=best if best is not None else 8, block_d=bd)
+    return TileChoice(block=best)
 
 
 def _search_scatter(key: TileKey) -> TileChoice:
     ovh = _STEP_OVH[key.platform]
     onehot_cap = _ONEHOT_CAP[key.platform]
     cap = _TILE_BYTES_CAP[key.platform]
-    item = _itemsize(key.dtype)
-    hi_v = min(_MAX_BLOCK, _next_pow2(key.rows))
-    hi_n = min(_MAX_BLOCK, _next_pow2(key.lanes))
+    narrow = key.width < _LANES
+    # the kernel's geometry: output lines (narrow) or rows, and lanes
+    rows = (_ceil_div(key.rows * key.width, _LANES) if narrow else key.rows)
+    width = _LANES if narrow else _ceil_div(key.width, _LANES) * _LANES
+    lanes = key.lanes * (key.width if narrow else 1)
+    hi_v = min(_MAX_BLOCK, _next_pow2(rows))
+    hi_n = min(_MAX_BLOCK, _next_pow2(lanes))
     best, best_cost = None, None
     for bv in _pow2s(8, max(8, hi_v)):
-        for bn in _pow2s(8, max(8, hi_n)):
-            if bv * bn > onehot_cap:
-                continue
-            # residency: out tile + staged vals chunk (x2 buffers) + onehot
-            tile_b = (bv * key.width + 2 * bn * key.width + bv * bn) * item
+        if bv * _LANES > onehot_cap:
+            continue
+        for bn in _pow2s(INDEX_BLOCK, max(INDEX_BLOCK, hi_n)):
+            # residency: out + dst tiles and the staged idx/vals chunks,
+            # all double-buffered, plus the per-slice one-hot
+            vals_b = (tiled_bytes(bn // _LANES, _LANES, key.dtype) if narrow
+                      else tiled_bytes(bn, width, key.dtype))
+            tile_b = (4 * tiled_bytes(bv, width, key.dtype)
+                      + 2 * (vals_b + tiled_bytes(bn // _LANES, _LANES,
+                                                  "int32"))
+                      + tiled_bytes(bv, _LANES, key.dtype))
             if tile_b > cap:
                 continue
-            steps = (key.batch * _ceil_div(max(1, key.rows), bv)
-                     * _ceil_div(max(1, key.lanes), bn))
-            work = bv * bn + (bv + bn) * key.width
+            steps = (key.batch * _ceil_div(max(1, rows), bv)
+                     * _ceil_div(max(1, lanes), bn))
+            work = bv * bn + (bv + bn) * width
             cost = steps * (ovh + work)
             if best_cost is None or cost < best_cost:
                 best, best_cost = (bv, bn), cost
     if best is None:
-        return TileChoice(block_v=8, block_n=8)
-    return TileChoice(block_v=best[0], block_n=best[1])
+        return TileChoice(block=INDEX_BLOCK, block_v=8)
+    return TileChoice(block=best[1], block_v=best[0])
 
 
 _SEARCHERS = {
-    "gather_vmem": _search_gather_vmem,
-    "gather_dma": _search_gather_dma,
+    "gather": _search_gather,
     "scatter": _search_scatter,
 }
 
@@ -281,6 +293,8 @@ def _key_to_wire(key: TileKey) -> str:
 
 def _key_from_wire(s: str) -> TileKey:
     op, batch, lanes, rows, width, dtype, platform = s.split(":")
+    if op not in _SEARCHERS:
+        raise ValueError(f"unknown autotune op {op!r}")
     return TileKey(op=op, batch=int(batch), lanes=int(lanes), rows=int(rows),
                    width=int(width), dtype=dtype, platform=platform)
 

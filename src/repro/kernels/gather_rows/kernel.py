@@ -1,28 +1,34 @@
-"""Scalar-prefetch DMA row gather — the TPU-native Spatter gather kernel.
+"""Line gather kernel — the TPU-native Spatter gather backend.
+
+The chip moves (8, 128) tiles, so the kernel never addresses a row
+narrower or wider than one 128-lane line (DESIGN.md §2.3): ops.py hands
+it the table as lane-dense lines ``(B, R, 128)`` and one index per line
+to fetch.  A row of width D >= 128 is D/128 consecutive lines; a
+narrower row is gathered element by element — element ``k`` of the flat
+table lives in line ``k >> 7``, lane ``k & 127``, and the kernel selects
+that lane after fetching the line.
 
 Two regimes, mirroring the paper's cache-resident vs memory-resident split
 (DESIGN.md §2):
 
-  * ``dma``  — the table stays in HBM (``pltpu.ANY``); the index buffer is
-    scalar-prefetched into SMEM and the kernel issues its own row DMAs
-    against a two-slot VMEM scratch: while row ``r``'s copy drains into
-    the output tile, row ``r+1``'s DMA is already in flight (explicit
-    double buffering, DESIGN.md §16).  Each grid step covers ``block_i``
-    rows, so the pipeline keeps one fetch ahead across the whole block —
-    the TPU analogue of the HW prefetcher's outstanding-miss depth
-    studied in paper Fig 4.
-  * ``vmem`` — small tables are staged whole into VMEM and gathered with an
-    in-register ``take`` over ``block_n`` rows per step (the "cache-resident"
-    regime: once the table is in VMEM, arbitrary reuse is free).
+  * ``dma``  — the table stays in HBM (``pl.ANY``) and the kernel
+    issues one async line copy per index: all ``block`` copies of a grid
+    step start before the first is waited on, so the step keeps
+    ``block`` fetches in flight — the TPU analogue of the HW prefetcher's
+    outstanding-miss depth studied in paper Fig 4.
+  * ``vmem`` — small tables are staged whole into VMEM and lines are
+    copied out with dynamic sublane loads (the "cache-resident" regime:
+    once the table is in VMEM, arbitrary reuse is free).
 
-The CUDA backend's trick of staging the index buffer in shared memory (paper
-§3.2) maps exactly onto scalar prefetch: indices live in SMEM for the whole
-kernel invocation.
+The index buffer reaches the kernel as a per-step SMEM block (the CUDA
+backend's shared-memory index staging, paper §3.2): each step reads its
+``block`` indices as scalars, so SMEM use does not grow with the pattern.
+The lane select needs the same indices as a vector; it reads them from a
+VMEM block of the same buffer.
 
-Both kernels are batch-NATIVE (DESIGN.md §2.2): the grid leads with the
-pattern-batch dim so a whole planner bucket — (B, V, D) tables, (B, N)
-indices — is ONE launch with the index buffers scalar-prefetched once;
-the single-pattern entry point in ops.py is just the B=1 case.
+The kernel is batch-NATIVE (DESIGN.md §2.2): the grid leads with the
+pattern-batch dim, so a whole planner bucket is ONE launch; the
+single-pattern entry point in ops.py is the B=1 case.
 """
 from __future__ import annotations
 
@@ -33,101 +39,104 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+LANES = 128
+SHIFT = 7              # log2(LANES): element k sits in line k >> SHIFT
 
-def _vmem_take_kernel(block_n: int, idx_ref, table_blk, out_blk):
+
+def _select_lanes(stage, idx_vmem, out_ref):
+    """out[0, g, l] = stage[g*128 + l, idx[g, l] & 127] per 128-line group.
+
+    The transpose puts element j of a group in lane j, so the one-hot
+    select reduces over sublanes and the result lands lane-dense.  The
+    lines are int32 words: an integer sum of one word and zeros is that
+    word, where a float sum would turn -0.0 into +0.0."""
+    sub = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
+    for g in range(stage.shape[0] // LANES):
+        lines_t = stage[g * LANES:(g + 1) * LANES, :].T     # [lane, elem]
+        lane = idx_vmem[0, g:g + 1, :] & (LANES - 1)         # (1, 128)
+        picked = jnp.where(sub == lane, lines_t, jnp.zeros_like(lines_t))
+        out_ref[0, g:g + 1, :] = jnp.sum(picked, axis=0, keepdims=True)
+
+
+def _gather_kernel(block: int, narrow: bool, hbm: bool, *refs):
+    if narrow:
+        idx_smem, idx_vmem, table_ref, out_ref, *scratch = refs
+        stage = scratch[0]
+    else:
+        idx_smem, table_ref, out_ref, *scratch = refs
+        stage = out_ref.at[0]
     b = pl.program_id(0)
-    i = pl.program_id(1)
-    rows = idx_ref[b, pl.ds(i * block_n, block_n)]
-    out_blk[...] = jnp.take(table_blk[0], rows, axis=0)[None]
+
+    def line(r):
+        k = idx_smem[r]
+        return jax.lax.shift_right_logical(k, SHIFT) if narrow else k
+
+    if hbm:
+        sem = scratch[-1]
+
+        def start(r, c):
+            pltpu.make_async_copy(table_ref.at[b, line(r)], stage.at[r],
+                                  sem).start()
+            return c
+
+        def wait(r, c):
+            # every copy moves one line: each wait retires one of them
+            pltpu.make_async_copy(table_ref.at[b, 0], stage.at[0],
+                                  sem).wait()
+            return c
+
+        jax.lax.fori_loop(0, block, start, 0)
+        jax.lax.fori_loop(0, block, wait, 0)
+    else:
+        def copy(r, c):
+            stage[pl.ds(r, 1), :] = table_ref[0, pl.ds(line(r), 1), :]
+            return c
+
+        jax.lax.fori_loop(0, block, copy, 0)
+    if narrow:
+        _select_lanes(stage, idx_vmem, out_ref)
 
 
-def gather_rows_vmem(table: jax.Array, idx: jax.Array, *,
-                     block_n: int, interpret: bool) -> jax.Array:
-    """VMEM-resident gather: (B, V, D) tables, (B, N) idx -> (B, N, D).
+def gather_lines(table: jax.Array, idx: jax.Array, *, block: int,
+                 narrow: bool, hbm: bool, interpret: bool) -> jax.Array:
+    """One launch for the whole pattern batch over lane-dense lines.
 
-    One launch for the whole pattern batch; pattern b's table is staged
-    whole per b-step.  Caller guarantees n % block_n == 0 (ops.py pads).
+    ``table`` (B, R, 128); ``idx`` (B, N) in range, N a multiple of
+    ``block`` and ``block`` a multiple of 1024 (the SMEM index block's
+    tiling).  Wide: ``idx`` are line indices and the result is the
+    fetched lines (B, N, 128).  Narrow: ``idx`` are flat element indices
+    and the result is the elements, lane-dense (B, N // 128, 128).
     """
     bsz, n = idx.shape
-    _, v, d = table.shape
-    assert n % block_n == 0, (n, block_n)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(bsz, n // block_n),
-        in_specs=[pl.BlockSpec((1, v, d), lambda b, i, idx_ref: (b, 0, 0))],
-        out_specs=pl.BlockSpec((1, block_n, d),
-                               lambda b, i, idx_ref: (b, i, 0)),
-    )
+    _, r, _ = table.shape
+    assert n % block == 0, (n, block)
+    nblk = n // block
+    in_specs = [pl.BlockSpec((block,), lambda b, i: (b * nblk + i,),
+                             memory_space=pltpu.SMEM)]
+    operands = [idx.reshape(bsz * n)]
+    scratch = []
+    if narrow:
+        dense = pl.BlockSpec((1, block // LANES, LANES),
+                             lambda b, i: (b, i, 0))
+        in_specs.append(dense)
+        operands.append(idx.reshape(bsz, n // LANES, LANES))
+        scratch.append(pltpu.VMEM((block, LANES), table.dtype))
+        out_shape, out_spec = (bsz, n // LANES, LANES), dense
+    else:
+        out_shape = (bsz, n, LANES)
+        out_spec = pl.BlockSpec((1, block, LANES), lambda b, i: (b, i, 0))
+    if hbm:
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        scratch.append(pltpu.SemaphoreType.DMA(()))
+    else:
+        in_specs.append(pl.BlockSpec((1, r, LANES), lambda b, i: (b, 0, 0)))
+    operands.append(table)
     return pl.pallas_call(
-        functools.partial(_vmem_take_kernel, block_n),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bsz, n, d), table.dtype),
+        functools.partial(_gather_kernel, block, narrow, hbm),
+        grid=(bsz, nblk),
+        in_specs=in_specs,
+        out_specs=out_spec,
+        scratch_shapes=scratch,
+        out_shape=jax.ShapeDtypeStruct(out_shape, table.dtype),
         interpret=interpret,
-    )(idx, table)
-
-
-def _dma_rows_kernel(block_i: int, block_d: int,
-                     idx_ref, table_ref, out_blk, scratch, sems):
-    # Explicit double buffer: two scratch slots, two DMA semaphores.  Row
-    # r+1's copy is started before row r's is consumed, so the writeback
-    # of each row overlaps the fetch of the next (prefetch depth 1 — the
-    # slot count bounds it, not the block size).
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-
-    def dma(r, slot):
-        row = idx_ref[b, i * block_i + r]
-        return pltpu.make_async_copy(
-            table_ref.at[b, row, pl.ds(j * block_d, block_d)],
-            scratch.at[slot], sems.at[slot])
-
-    dma(0, 0).start()                                      # warm-up fetch
-
-    def body(r, carry):
-        slot = jax.lax.rem(r, 2)
-
-        @pl.when(r + 1 < block_i)
-        def _prefetch():
-            dma(r + 1, jax.lax.rem(r + 1, 2)).start()
-
-        dma(r, slot).wait()
-        out_blk[0, r, :] = scratch[slot]
-        return carry
-
-    jax.lax.fori_loop(0, block_i, body, 0)
-
-
-def gather_rows_dma(table: jax.Array, idx: jax.Array, *,
-                    block_d: int, block_i: int, interpret: bool) -> jax.Array:
-    """HBM-resident gather: grid (B, N/block_i, D/block_d), block_i rows/step.
-
-    The table never enters the automatic pipeline — it is bound in
-    ``pltpu.ANY`` and the kernel gathers rows itself with double-buffered
-    async copies.  Caller guarantees n % block_i == 0 and
-    d % block_d == 0 (ops.py pads).
-    """
-    bsz, n = idx.shape
-    _, v, d = table.shape
-    assert d % block_d == 0, (d, block_d)
-    assert n % block_i == 0, (n, block_i)
-    grid = (bsz, n // block_i, d // block_d)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec((1, block_i, block_d),
-                               lambda b, i, j, idx_ref: (b, i, j)),
-        scratch_shapes=[
-            pltpu.VMEM((2, block_d), table.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_dma_rows_kernel, block_i, block_d),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bsz, n, d), table.dtype),
-        interpret=interpret,
-    )(idx, table)
+    )(*operands)

@@ -1,12 +1,14 @@
-"""Public jit'd wrappers for the row-gather kernels.
+"""Public jit'd wrappers for the line-gather kernel.
 
-Picks the VMEM-resident regime for small tables and the DMA regime
-otherwise, pads ragged shapes, and defaults to interpret mode off-TPU.
-Block sizes default to the deterministic per-geometry autotuner
-(``kernels.autotune``); passing any block explicitly bypasses the search
-for that block.  ``gather_rows_batched`` runs a whole pattern batch (a
-planner bucket) as one kernel launch (DESIGN.md §2.2); ``gather_rows``
-is its B=1 case.
+Lays the table out as lane-dense 128-lane lines (DESIGN.md §2.3), expands
+each row index into the line (or element) indices the kernel fetches,
+picks the VMEM-resident regime for small tables and the DMA regime
+otherwise — both from the table's tiled bytes — and defaults to interpret
+mode off-TPU.  The block size defaults to the deterministic per-geometry
+autotuner (``kernels.autotune``); passing one explicitly bypasses the
+search.  ``gather_rows_batched`` runs a whole pattern batch (a planner
+bucket) as one kernel launch (DESIGN.md §2.2); ``gather_rows`` is its
+B=1 case.
 """
 from __future__ import annotations
 
@@ -18,13 +20,14 @@ import jax.numpy as jnp
 from . import kernel
 from .. import autotune
 
-# VMEM on v5e is ~128 MiB/core but the pipeline needs headroom; stage tables
-# whole only when they take at most this many bytes.
+LANES = kernel.LANES
+# VMEM on v5e is 128 MiB/core but a kernel's default scoped window is
+# 16 MiB and the pipeline double-buffers the table block: stage tables
+# whole only when their tiled bytes are at most this.
 _VMEM_TABLE_BYTES = 4 * 1024 * 1024
-# legacy fixed tiles — served when the autotuner is disabled()
-# (autotune.LEGACY mirrors these; a drift test pins them equal)
-_DEFAULT_BLOCK_N = 64
-_DEFAULT_BLOCK_I = 8
+# legacy fixed block — served when the autotuner is disabled()
+# (autotune.LEGACY mirrors it; a drift test pins them equal)
+_DEFAULT_BLOCK = autotune.INDEX_BLOCK
 
 
 def _should_interpret(interpret: bool | None) -> bool:
@@ -33,61 +36,77 @@ def _should_interpret(interpret: bool | None) -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _pad_idx(idx, multiple: int):
-    n = idx.shape[-1]
-    pad = (-n) % multiple
-    if not pad:
-        return idx
-    fill = jnp.zeros(idx.shape[:-1] + (pad,), jnp.int32)   # row 0: harmless
-    return jnp.concatenate([idx, fill], axis=-1)
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
-def _pick_block_d(d: int) -> int:
-    block_d = d if d <= 512 else 512
-    while d % block_d:
-        block_d //= 2
-        if block_d == 0:
-            return d
-    return block_d
+def table_lines(v: int, d: int) -> int:
+    """Lines of the lane-dense layout of a (V, D) table (D padded to a
+    lane multiple when D >= 128; narrower rows packed element by
+    element)."""
+    if d < LANES:
+        return -(-v * d // LANES)
+    return v * (_round_up(d, LANES) // LANES)
 
 
-@functools.partial(jax.jit, static_argnames=("mode", "block_n", "block_d",
-                                             "block_i", "interpret"))
-def _gather_rows_batched(table, idx, mode: str, block_n: int, block_d: int,
-                         block_i: int, interpret: bool):
+def _legal_block(block: int, n: int) -> tuple[int, int]:
+    """(block, padded n): a multiple of 1024 indices per step — the SMEM
+    index block's tiling — never beyond the padded index count."""
+    unit = autotune.INDEX_BLOCK
+    block = max(unit, _round_up(block, unit))
+    block = min(block, _round_up(max(1, n), unit))
+    return block, _round_up(max(1, n), block)
+
+
+@functools.partial(jax.jit, static_argnames=("hbm", "block", "interpret"))
+def _gather_rows_batched(table, idx, hbm: bool, block: int, interpret: bool):
     bsz, n = idx.shape
     _, v, d = table.shape
-    idx = idx.astype(jnp.int32)
-    if mode == "vmem":
-        out = kernel.gather_rows_vmem(table, _pad_idx(idx, block_n),
-                                      block_n=block_n, interpret=interpret)
-        return out[:, :n]
-    # dma mode: pad D up to a block_d multiple, N up to a block_i multiple
-    pad_d = (-d) % block_d
-    if pad_d:
-        table = jnp.pad(table, ((0, 0), (0, 0), (0, pad_d)))
-    out = kernel.gather_rows_dma(table, _pad_idx(idx, block_i),
-                                 block_d=block_d, block_i=block_i,
-                                 interpret=interpret)
-    return out[:, :n, :d]
+    dtype = table.dtype
+    if dtype.itemsize != 4:
+        table = table.astype(jnp.float32)
+    word = table.dtype
+    # the kernel moves and selects 32-bit words, never float values, so
+    # every bit pattern (-0.0 included) arrives unchanged
+    table = jax.lax.bitcast_convert_type(table, jnp.int32)
+    idx = jnp.clip(idx.astype(jnp.int32), 0, v - 1)
+    narrow = d < LANES
+    r = table_lines(v, d)
+    if narrow:
+        flat = table.reshape(bsz, v * d)
+        lines = jnp.pad(flat, ((0, 0), (0, r * LANES - v * d)))
+        fetch = (idx[..., None] * d + jnp.arange(d, dtype=jnp.int32))
+    else:
+        dp = _round_up(d, LANES)
+        lines = jnp.pad(table, ((0, 0), (0, 0), (0, dp - d)))
+        m = dp // LANES
+        fetch = idx[..., None] * m + jnp.arange(m, dtype=jnp.int32)
+    lines = lines.reshape(bsz, r, LANES)
+    fetch = fetch.reshape(bsz, -1)
+    n_f = fetch.shape[1]
+    blk, n_pad = _legal_block(block, n_f)
+    fetch = jnp.pad(fetch, ((0, 0), (0, n_pad - n_f)))      # line 0: harmless
+    out = kernel.gather_lines(lines, fetch, block=blk, narrow=narrow,
+                              hbm=hbm, interpret=interpret)
+    out = out.reshape(bsz, -1)[:, :n_f * (1 if narrow else LANES)]
+    out = out.reshape(bsz, n, -1)[..., :d]
+    return jax.lax.bitcast_convert_type(out, word).astype(dtype)
 
 
 def gather_rows_batched(table: jax.Array, idx: jax.Array, *,
                         mode: str = "auto",
-                        block_n: int | None = None,
-                        block_d: int | None = None,
-                        block_i: int | None = None,
+                        block: int | None = None,
                         interpret: bool | None = None) -> jax.Array:
     """Batched gather: (B, V, D) tables, (B, N) idx -> (B, N, D).
 
-    One kernel launch for the whole pattern batch (a planner bucket), with
-    the index buffers scalar-prefetched once — not a vmap of per-pattern
-    launches.  The regime choice sizes VMEM per b-step, so it uses one
-    pattern's table bytes, not the whole stack's.  Blocks left ``None``
-    come from the autotuner, keyed on the geometry the kernel actually
-    sees (the local shard under a lane-sharded launch) — a pure function
-    of shapes, so one jit signature per geometry and ``misses`` stays an
-    exact compile count upstream.
+    One kernel launch for the whole pattern batch (a planner bucket).  The
+    regime choice sizes VMEM per b-step, so it uses one pattern's tiled
+    table bytes, not the whole stack's.  ``block`` sets the indices per
+    grid step in either regime; left ``None`` it comes from the
+    autotuner, keyed on the geometry the kernel actually sees (the local
+    shard under a lane-sharded launch) — a pure function of shapes, so
+    one jit signature per geometry and ``misses`` stays an exact compile
+    count upstream.  Out-of-range indices are clamped to the table.
     """
     if table.ndim != 3 or idx.ndim != 2 or table.shape[0] != idx.shape[0]:
         raise ValueError(f"expected (B,V,D) table and (B,N) idx, got "
@@ -96,28 +115,21 @@ def gather_rows_batched(table: jax.Array, idx: jax.Array, *,
     bsz, n = idx.shape
     _, v, d = table.shape
     if mode == "auto":
-        per_pattern_bytes = v * d * table.dtype.itemsize
-        mode = "vmem" if per_pattern_bytes <= _VMEM_TABLE_BYTES else "dma"
-    if block_n is None or block_i is None or block_d is None:
-        choice = autotune.choose(autotune.TileKey(
-            op="gather_vmem" if mode == "vmem" else "gather_dma",
-            batch=bsz, lanes=n, rows=v, width=d, dtype=table.dtype.name,
-            platform="interpret" if interp else "tpu"))
-        if block_n is None:
-            block_n = choice.block_n or _DEFAULT_BLOCK_N
-        if block_i is None:
-            block_i = choice.block_i or _DEFAULT_BLOCK_I
-        if block_d is None:
-            block_d = choice.block_d or _pick_block_d(d)
-    block_n = min(block_n, max(1, n))
-    block_i = min(block_i, max(1, n))
-    return _gather_rows_batched(table, idx, mode, block_n, block_d, block_i,
-                                interp)
+        tiled = autotune.tiled_bytes(table_lines(v, d), LANES, "float32")
+        mode = "vmem" if tiled <= _VMEM_TABLE_BYTES else "dma"
+    if mode not in ("vmem", "dma"):
+        raise ValueError(f"unknown gather mode {mode!r}")
+    if block is None:
+        block = autotune.choose(autotune.TileKey(
+            op="gather", batch=bsz, lanes=n, rows=v, width=d,
+            dtype=table.dtype.name,
+            platform="interpret" if interp else "tpu")).block \
+            or _DEFAULT_BLOCK
+    return _gather_rows_batched(table, idx, mode == "dma", block, interp)
 
 
 def gather_rows(table: jax.Array, idx: jax.Array, *, mode: str = "auto",
-                block_n: int | None = None, block_d: int | None = None,
-                block_i: int | None = None,
+                block: int | None = None,
                 interpret: bool | None = None) -> jax.Array:
     """Gather rows of ``table`` (V, D) at positions ``idx`` (N,) -> (N, D).
 
@@ -127,5 +139,4 @@ def gather_rows(table: jax.Array, idx: jax.Array, *, mode: str = "auto",
         raise ValueError(f"expected (V,D) table and (N,) idx, got "
                          f"{table.shape} / {idx.shape}")
     return gather_rows_batched(table[None], idx[None], mode=mode,
-                               block_n=block_n, block_d=block_d,
-                               block_i=block_i, interpret=interpret)[0]
+                               block=block, interpret=interpret)[0]

@@ -1,14 +1,16 @@
-"""Public jit'd wrappers for the MXU scatter kernels.
+"""Public jit'd wrappers for the MXU scatter kernel.
 
 ``scatter_add_rows_batched`` / ``scatter_store_rows_batched`` run a whole
-pattern batch (a planner bucket) as ONE kernel launch with the (B, N)
-index buffer scalar-prefetched once (DESIGN.md §2.2); the per-pattern
-entry points are the B=1 case of the same kernels.  Store mode expects
-its index buffer pre-deduped on the host (dropped lanes routed out of
-range — backends.keep_last_mask), so the kernel is a single pass with no
-sort and no coverage-count launch.  Block sizes default to the
-deterministic per-geometry autotuner (``kernels.autotune``); passing a
-block explicitly bypasses the search.
+pattern batch (a planner bucket) as ONE kernel launch (DESIGN.md §2.2);
+the per-pattern entry points are the B=1 case of the same kernel.  Rows
+narrower than a 128-lane line are scattered element by element into the
+lane-dense flat table; wider rows are padded to a lane multiple
+(DESIGN.md §2.3).  Store mode expects its index buffer pre-deduped on
+the host (dropped lanes routed out of range — backends.keep_last_mask),
+so the kernel is a single pass with no sort and no coverage-count
+launch; it moves 32-bit words, so every stored bit pattern arrives
+unchanged.  Block sizes default to the deterministic per-geometry autotuner
+(``kernels.autotune``); passing a block explicitly bypasses the search.
 """
 from __future__ import annotations
 
@@ -20,10 +22,11 @@ import jax.numpy as jnp
 from . import kernel
 from .. import autotune
 
+LANES = kernel.LANES
 # legacy fixed tiles — served when the autotuner is disabled()
 # (autotune.LEGACY mirrors these; a drift test pins them equal)
 _DEFAULT_BLOCK_V = 128
-_DEFAULT_BLOCK_N = 128
+_DEFAULT_BLOCK_N = autotune.INDEX_BLOCK
 
 
 def _oob() -> int:
@@ -36,8 +39,8 @@ def _should_interpret(interpret: bool | None) -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _clip_blocks(v: int, n: int, block_v: int, block_n: int):
-    return min(block_v, max(8, v)), min(block_n, max(8, n))
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
 def _pick_blocks(v: int, n: int, bsz: int, d: int, dtype,
@@ -52,22 +55,70 @@ def _pick_blocks(v: int, n: int, bsz: int, d: int, dtype,
         if block_v is None:
             block_v = choice.block_v or _DEFAULT_BLOCK_V
         if block_n is None:
-            block_n = choice.block_n or _DEFAULT_BLOCK_N
-    return _clip_blocks(v, n, block_v, block_n)
+            block_n = choice.block or _DEFAULT_BLOCK_N
+    return block_v, block_n
 
 
-def _pad_lanes(idx, vals, block_n: int):
-    """Pad the lane dim to a block_n multiple; pad lanes point past every
-    tile so the one-hot drops them."""
-    bsz, n = idx.shape
-    pad_n = (-n) % block_n
-    if not pad_n:
-        return idx, vals
-    idx = jnp.concatenate(
-        [idx, jnp.full((bsz, pad_n), _oob(), jnp.int32)], axis=1)
-    vals = jnp.concatenate(
-        [vals, jnp.zeros((bsz, pad_n, vals.shape[2]), vals.dtype)], axis=1)
-    return idx, vals
+def _legal_blocks(rows: int, n_e: int, block_v: int, block_n: int):
+    """(block_v, block_n, n_pad) legal for the (8, 128) tiling: ``block_v``
+    a multiple of 8 no larger than the padded output rows, ``block_n`` a
+    multiple of 1024 indices or one chunk of all ``n_pad`` of them."""
+    n_pad = _round_up(max(1, n_e), LANES)
+    if block_n >= n_pad:
+        block_n = n_pad                                # one chunk
+    else:
+        block_n = _round_up(block_n, autotune.INDEX_BLOCK)
+        n_pad = _round_up(n_e, block_n)
+    block_v = _round_up(min(block_v, _round_up(rows, 8)), 8)
+    return block_v, block_n, n_pad
+
+
+def _layout(idx, vals, v: int, block_v: int, block_n: int):
+    """Lay a scatter out for the kernel: (idx, vals, out_rows, block_v,
+    block_n, narrow) with lane-dense indices, lines or padded rows, and
+    blocks legalized for the (8, 128) tiling."""
+    bsz, n, d = vals.shape
+    idx = idx.astype(jnp.int32)
+    idx = jnp.where((idx >= 0) & (idx < v), idx, _oob())
+    narrow = d < LANES
+    if narrow:
+        # element k = row * d + e; dropped rows stay out of every line
+        e = jnp.arange(d, dtype=jnp.int32)
+        idx = jnp.where(idx[..., None] == _oob(), _oob(),
+                        idx[..., None] * d + e).reshape(bsz, n * d)
+        vals = vals.reshape(bsz, n * d)
+        rows = -(-v * d // LANES)
+    else:
+        vals = jnp.pad(vals, ((0, 0), (0, 0), (0, _round_up(d, LANES) - d)))
+        rows = v
+    n_e = idx.shape[1]
+    block_v, block_n, n_pad = _legal_blocks(rows, n_e, block_v, block_n)
+    idx = jnp.pad(idx, ((0, 0), (0, n_pad - n_e)), constant_values=_oob())
+    pad_vals = ((0, 0), (0, n_pad - n_e)) + (((0, 0),) if not narrow else ())
+    vals = jnp.pad(vals, pad_vals)
+    if narrow:
+        vals = vals.reshape(bsz, n_pad // LANES, LANES)
+    idx = idx.reshape(bsz, n_pad // LANES, LANES)
+    return idx, vals, _round_up(rows, block_v), block_v, block_n, narrow
+
+
+def _to_lines(table, out_rows: int):
+    """(B, V, D) -> the kernel's output layout with ``out_rows`` rows."""
+    bsz, v, d = table.shape
+    if d < LANES:
+        flat = table.reshape(bsz, v * d)
+        flat = jnp.pad(flat, ((0, 0), (0, out_rows * LANES - v * d)))
+        return flat.reshape(bsz, out_rows, LANES)
+    return jnp.pad(table, ((0, 0), (0, out_rows - v),
+                           (0, _round_up(d, LANES) - d)))
+
+
+def _from_lines(out, v: int, d: int):
+    """Inverse of ``_to_lines``: back to (B, V, D)."""
+    bsz = out.shape[0]
+    if d < LANES:
+        return out.reshape(bsz, -1)[:, :v * d].reshape(bsz, v, d)
+    return out[:, :v, :d]
 
 
 # ---------------------------------------------------------------------------
@@ -78,12 +129,14 @@ def _pad_lanes(idx, vals, block_n: int):
                    static_argnames=("v", "block_v", "block_n", "interpret"))
 def _scatter_add_batched(idx, vals, v: int, block_v: int, block_n: int,
                          interpret: bool):
-    idx, vals = _pad_lanes(idx.astype(jnp.int32), vals, block_n)
-    v_padded = v + ((-v) % block_v)
-    out = kernel.scatter_add_rows_kernel(
-        idx, vals, v_padded, block_v=block_v, block_n=block_n,
-        interpret=interpret)
-    return out[:, :v]
+    dtype = vals.dtype
+    d = vals.shape[2]
+    idx, lines, rows, block_v, block_n, narrow = _layout(
+        idx, vals.astype(jnp.float32), v, block_v, block_n)
+    out = kernel.scatter_lines(idx, lines, rows, narrow=narrow,
+                               block_v=block_v, block_n=block_n,
+                               interpret=interpret)
+    return _from_lines(out, v, d).astype(dtype)
 
 
 def scatter_add_rows_batched(idx: jax.Array, vals: jax.Array, v: int, *,
@@ -123,25 +176,37 @@ def scatter_add_rows(idx: jax.Array, vals: jax.Array, v: int, *,
 # single-pass store
 # ---------------------------------------------------------------------------
 
+def _word_dtype(dtype):
+    """The 32-bit type a store moves: narrower floats widen to float32."""
+    return dtype if jnp.dtype(dtype).itemsize == 4 else jnp.float32
+
+
 @functools.partial(jax.jit,
                    static_argnames=("block_v", "block_n", "with_covered",
                                     "interpret"))
 def _scatter_store_batched(dst, idx, vals, block_v: int, block_n: int,
                            with_covered: bool, interpret: bool):
-    bsz, _, d = vals.shape
-    v = dst.shape[1]
-    idx, vals = _pad_lanes(idx.astype(jnp.int32), vals, block_n)
-    pad_v = (-v) % block_v
-    if pad_v:
-        dst = jnp.concatenate(
-            [dst, jnp.zeros((bsz, pad_v, d), dst.dtype)], axis=1)
-    out = kernel.scatter_store_rows_kernel(
-        idx, vals, dst, block_v=block_v, block_n=block_n,
-        with_cov=with_covered, interpret=interpret)
+    dtype = dst.dtype
+    word = _word_dtype(dtype)
+    _, v, d = dst.shape
+
+    def words(x):
+        # the kernel moves int32 words, never float values
+        return jax.lax.bitcast_convert_type(x.astype(word), jnp.int32)
+
+    idx, lines, rows, block_v, block_n, narrow = _layout(
+        idx, words(vals), v, block_v, block_n)
+    out = kernel.scatter_lines(idx, lines, rows,
+                               dst=_to_lines(words(dst), rows),
+                               narrow=narrow, block_v=block_v,
+                               block_n=block_n, with_cov=with_covered,
+                               interpret=interpret)
+    cov = None
     if with_covered:
         out, cov = out
-        return out[:, :v], cov[:, :v]
-    return out[:, :v]
+    out = jax.lax.bitcast_convert_type(_from_lines(out, v, d),
+                                       word).astype(dtype)
+    return (out, _from_lines(cov, v, d)) if with_covered else out
 
 
 def scatter_store_rows_batched(dst: jax.Array, idx: jax.Array,
@@ -156,7 +221,7 @@ def scatter_store_rows_batched(dst: jax.Array, idx: jax.Array,
     each in-range index value occurs at most once per batch row (the host
     keep mask already dropped duplicate writes by routing them out of
     range); out-of-range indices are dropped.  With ``with_covered`` the
-    same single launch also returns the (B, V) int32 coverage map (1
+    same single launch also returns the (B, V, D) int32 coverage map (1
     where this call wrote) — the lane-sharded combine's ballot.
     """
     if (vals.ndim != 3 or idx.ndim != 2 or dst.ndim != 3

@@ -8,6 +8,8 @@ from __future__ import annotations
 
 import jax
 
+from repro.core.compat import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 (data, model) per pod; (2, 16, 16) (pod, data, model) across.
@@ -17,11 +19,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(model: int = 1):
     """Debug mesh over however many devices exist (tests, CPU examples)."""
     n = len(jax.devices())
     assert n % model == 0, (n, model)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))

@@ -733,6 +733,8 @@ def main(argv=None) -> None:
     ap.add_argument("--verbose", action="store_true",
                     help="log one line per handled request")
     args = ap.parse_args(argv)
+    from repro.core.compile_cache import use_compile_cache
+    use_compile_cache()
     faults = (FaultInjector.from_spec(args.faults, seed=args.fault_seed)
               if args.faults else FaultInjector.from_env())
     daemon = SpatterDaemon(args.host, args.port, quiet=not args.verbose,

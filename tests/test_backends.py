@@ -103,10 +103,11 @@ def test_sharded_engine_subprocess():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import sys; sys.path.insert(0, %r)
+        from repro.core.compat import make_mesh
         import jax, numpy as np
         from repro.core import GSEngine, make_pattern
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         p = make_pattern("UNIFORM:8:2", kind="gather", delta=16, count=128)
         eng = GSEngine(p, backend="xla")
         fn, args = eng.sharded(mesh, "data")

@@ -10,6 +10,7 @@ CODE = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import sys; sys.path.insert(0, %r)
+    from repro.core.compat import make_mesh
     import dataclasses, jax, jax.numpy as jnp, numpy as np
     from repro.configs import get_smoke_config
     from repro.data import TokenPipeline
@@ -21,7 +22,7 @@ CODE = textwrap.dedent("""
     cfg = dataclasses.replace(get_smoke_config("llama3-8b"),
                               dtype="float32", remat="none")
     model = Model(cfg)
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_mesh((4,), ("data",))
     opt_cfg = AdamWConfig(lr=1e-3, weight_decay=0.0)
     pipe = TokenPipeline(vocab=cfg.vocab, seq_len=16, global_batch=8,
                          seed=0)

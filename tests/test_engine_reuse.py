@@ -6,12 +6,12 @@ scatter engine — and ``sharded()`` after ``run()`` — die with
 "buffer has been deleted or donated".  Repeated execution on one engine
 is the serving regime, so every backend pins it here.
 """
-import jax
 import numpy as np
 import pytest
 
 from repro.core import GSEngine, make_pattern
 from repro.core import backends as B
+from repro.core.compat import make_mesh
 
 
 def _scatter_pattern():
@@ -31,7 +31,7 @@ def test_scatter_run_twice(backend):
 def test_scatter_sharded_after_run(backend):
     eng = GSEngine(_scatter_pattern(), backend=backend)
     eng.run(runs=1)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     fn, args = eng.sharded(mesh)   # crashed before the fix (stale dst)
     out1 = np.asarray(fn(*args))
     # and the sharded executable itself is reusable: build() hands out a

@@ -100,6 +100,30 @@ def test_hlo_stats_census():
     assert isinstance(stats["shardings"], set)
 
 
+def test_hlo_stats_reads_shardy_annotations():
+    # a sharded module spells each value's sharding against a named mesh:
+    # one {} per dim listing the axes it is split over ({} = unsplit,
+    # ? = open to the partitioner); hlo_stats turns each into the shard
+    # count per dim
+    text = textwrap.dedent("""
+        module @jit_f attributes {mhlo.num_partitions = 8 : i32} {
+          sdy.mesh @mesh = <["data"=4, "lane"=2]>
+          func.func public @main(
+              %arg0: tensor<8x256xf32> {sdy.sharding = #sdy.sharding<@mesh, [{"data"}, {"lane"}]>},
+              %arg1: tensor<8x4x16xf32> {sdy.sharding = #sdy.sharding<@mesh, [{"data", "lane"}, {}, {}]>},
+              %arg2: tensor<8x256xi32> {sdy.sharding = #sdy.sharding<@mesh, [{}, {"lane", ?}]>},
+              %arg3: tensor<8x256xi32> {sdy.sharding = #sdy.sharding<@mesh, [{?}, {}]>})
+              -> (tensor<8x256xf32> {sdy.sharding = #sdy.sharding<@mesh, [{"data"}, {"lane"}]>}) {
+            return %arg0 : tensor<8x256xf32>
+          }
+        }
+    """)
+    stats = hlo.hlo_stats(text)
+    assert stats["num_partitions"] == 8
+    assert stats["shardings"] == {"devices=[4,2]", "devices=[8,1,1]",
+                                  "devices=[1,2]", "devices=[1,1]"}
+
+
 # ---------------------------------------------------------------------------
 # dedup pins: every consumer resolves to THIS walker
 # ---------------------------------------------------------------------------

@@ -58,6 +58,21 @@ class TestGatherRows:
             out = gops.gather_rows(table, idx, mode=mode)
             np.testing.assert_allclose(out, np.asarray(table)[idx])
 
+    @pytest.mark.parametrize("d", [1, 128])
+    @pytest.mark.parametrize("mode", ["vmem", "dma"])
+    def test_bit_exact_signed_zero(self, mode, d):
+        # a gather copies bits: -0.0, inf and NaN payloads come back as
+        # they went in (a seeded float32 table of 2^26 normals holds a
+        # few exact -0.0s, so paper-scale digests depend on it)
+        table = np.asarray(RNG.standard_normal((256, d)), np.float32)
+        table[3] = -0.0
+        table[5] = np.inf
+        table[7] = np.float32(1e-40)                    # subnormal
+        idx = np.asarray([3, 5, 7, 3, 0, 255], np.int32)
+        out = gops.gather_rows(jnp.asarray(table), jnp.asarray(idx),
+                               mode=mode)
+        assert np.asarray(out).tobytes() == table[idx].tobytes()
+
     def test_auto_mode_selection(self):
         small = jnp.zeros((64, 16), jnp.float32)
         big = jnp.zeros((1 << 15, 512), jnp.float32)    # > VMEM budget
@@ -203,26 +218,77 @@ class TestBatchedKernels:
 
 
 class TestGatherMultiRowBlocking:
-    """dma regime multi-row blocking (block_i rows per grid step)."""
+    """dma regime multi-row blocking (``block`` indices per grid step)."""
 
     @pytest.mark.parametrize("n", [1, 3, 7, 8, 9, 64, 513])
-    @pytest.mark.parametrize("block_i", [1, 4, 8])
-    def test_ragged_n(self, n, block_i):
+    @pytest.mark.parametrize("block", [1024, 2048, 4096])
+    def test_ragged_n(self, n, block):
         table = jnp.asarray(RNG.standard_normal((100, 16)), jnp.float32)
         idx = jnp.asarray(RNG.integers(0, 100, n), jnp.int32)
-        out = gops.gather_rows(table, idx, mode="dma", block_i=block_i)
+        out = gops.gather_rows(table, idx, mode="dma", block=block)
         np.testing.assert_array_equal(
             np.asarray(out), np.asarray(table)[np.asarray(idx)])
 
     def test_block_i_invariance(self):
-        """Results are invariant to the blocking factor."""
+        """Results are invariant to the blocking factor: 100 rows of 48
+        elements are 4800 fetches, so each block is its own grid."""
         table = jnp.asarray(RNG.standard_normal((64, 48)), jnp.float32)
-        idx = jnp.asarray(RNG.integers(0, 64, 37), jnp.int32)
+        idx = jnp.asarray(RNG.integers(0, 64, 100), jnp.int32)
         ref = np.asarray(gops.gather_rows(table, idx, mode="dma",
-                                          block_i=1))
-        for block_i in (2, 4, 8, 16):
-            out = gops.gather_rows(table, idx, mode="dma", block_i=block_i)
+                                          block=1024))
+        grids = {gops._legal_block(b, 4800) for b in (1024, 2048, 4096,
+                                                       8192)}
+        assert len(grids) == 4
+        for block in (2048, 4096, 8192):
+            out = gops.gather_rows(table, idx, mode="dma", block=block)
             np.testing.assert_array_equal(np.asarray(out), ref)
+
+
+class TestOneHotWords:
+    """One-hot stores and gathers move 32-bit words: the Pallas store
+    kernel and the onehot backend return every bit pattern unchanged."""
+
+    @staticmethod
+    def _special(shape):
+        x = np.asarray(RNG.standard_normal(shape), np.float32)
+        x[0] = -0.0
+        x[1] = np.inf
+        x[2] = np.float32(1e-40)                        # subnormal
+        x[3] = np.nan
+        return x
+
+    @pytest.mark.parametrize("d", [1, 128])
+    def test_bit_exact_signed_zero(self, d):
+        # a special value lands as it is and leaves its neighbours in the
+        # same 128-lane slice alone (no 0 * inf = NaN spread)
+        v = 256
+        dst = np.asarray(RNG.standard_normal((v, d)), np.float32)
+        vals = self._special((6, d))
+        idx = np.asarray([3, 5, 7, 9, 11, 200], np.int32)
+        ref = dst.copy()
+        ref[idx] = vals
+        out = sops.scatter_store_rows(jnp.asarray(dst), jnp.asarray(idx),
+                                      jnp.asarray(vals))
+        assert np.asarray(out).tobytes() == ref.tobytes()
+
+    @pytest.mark.parametrize("d", [1, 8])
+    def test_onehot_backend_bit_exact(self, d):
+        from repro.core import backends as B
+        f = 64
+        src = self._special((f, d))
+        idx = np.asarray([0, 1, 2, 3, 9, 0], np.int32)
+        got = B.gather_onehot(jnp.asarray(src), jnp.asarray(idx))
+        assert np.asarray(got).tobytes() == src[idx].tobytes()
+        dst = np.asarray(RNG.standard_normal((f, d)), np.float32)
+        dst[40] = np.inf                                 # never written
+        vals = self._special((5, d))
+        sidx = np.asarray([7, 8, 7, 20, 30], np.int32)   # 7 twice: last wins
+        ref = dst.copy()
+        for i, j in enumerate(sidx):
+            ref[j] = vals[i]
+        out = B.scatter_onehot(jnp.asarray(dst), jnp.asarray(sidx),
+                               jnp.asarray(vals), mode="store")
+        assert np.asarray(out).tobytes() == ref.tobytes()
 
 
 class TestPagedDecode:

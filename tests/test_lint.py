@@ -126,8 +126,7 @@ def test_rule_fires_donation_honored():
 
 
 def test_rule_fires_no_f64_promotion_drift():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64():
         jaxpr = jax.make_jaxpr(lambda x: x * 2.0)(
             jnp.arange(8, dtype=jnp.float64))
     unit = unit_for(None, (jax.ShapeDtypeStruct((8,), np.float64),),

@@ -160,6 +160,7 @@ def test_moe_ep_equals_baseline_subprocess():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import sys; sys.path.insert(0, %r)
+        from repro.core.compat import make_mesh
         import dataclasses, jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_smoke_config
         from repro.models.moe import moe_defs, moe_apply_gspmd, moe_apply_ep
@@ -168,7 +169,7 @@ def test_moe_ep_equals_baseline_subprocess():
         cfg = dataclasses.replace(get_smoke_config("deepseek-v2-236b"),
                                   dtype="float32", n_experts=8, top_k=2,
                                   capacity_factor=8.0)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         p = init_tree(jax.random.PRNGKey(0), moe_defs(cfg), jnp.float32)
         x = jnp.asarray(np.random.default_rng(0).standard_normal(
             (4, 16, cfg.d_model)), jnp.float32)

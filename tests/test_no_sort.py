@@ -11,7 +11,6 @@ per bucket.  These invariants are now owned by the spatterlint rules
 rather than a private jaxpr walker, so the test and the lint can never
 disagree about what "no sort in the hot path" means.
 """
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -19,6 +18,7 @@ import pytest
 from repro.analysis.lint import run_rules, unit_for
 from repro.core import GSEngine, SuitePlan, make_pattern
 from repro.core import backends as B
+from repro.core.compat import make_mesh
 from repro.core.engine import make_host_buffers
 from repro.core.plan import ShardedExecutor, _assemble_bucket, \
     _build_executable
@@ -97,7 +97,7 @@ def test_bucket_store_executable_passes_lint(backend):
 
 @pytest.mark.parametrize("backend", B.BACKENDS)
 def test_sharded_bucket_store_executable_passes_lint(backend):
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     plan = SuitePlan.build([DUP])
     bucket = plan.buckets[0]
     args, _ = _assemble_bucket(plan, bucket, jnp.float32, 1, 0)
@@ -110,7 +110,7 @@ def test_sharded_bucket_store_executable_passes_lint(backend):
 
 
 def test_sharded_engine_store_passes_lint():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     fn, args = GSEngine(DUP, backend="xla").sharded(mesh, "data")
     _assert_rules_clean(fn, args, "xla", kind="scatter", mode="store",
                         cached=False, label="engine-sharded/xla")

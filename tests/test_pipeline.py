@@ -10,10 +10,11 @@ CODE = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import sys; sys.path.insert(0, %r)
+    from repro.core.compat import make_mesh
     import jax, jax.numpy as jnp, numpy as np
     from repro.runtime.pipeline import pipeline_apply
 
-    mesh = jax.make_mesh((4,), ("pipe",))
+    mesh = make_mesh((4,), ("pipe",))
     L, B, D = 8, 8, 16
     rng = np.random.default_rng(0)
     params = {"w": jnp.asarray(rng.standard_normal((L, D, D)) * 0.2,

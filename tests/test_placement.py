@@ -14,7 +14,6 @@ import sys
 import textwrap
 import threading
 
-import jax
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
@@ -22,6 +21,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core import (ExecutorCache, Placement, ShardedExecutor, SuitePlan,
                         as_placement, execute_bucket, make_pattern,
                         pad_lanes, run_suite)
+from repro.core.compat import make_mesh
 from repro.core.plan import ExecKey
 from repro.runtime.sharding import gs_specs
 
@@ -83,14 +83,14 @@ def test_gs_specs_2d():
 
 
 def test_placement_validation_and_strings():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     p = Placement(mesh, batch_axis="data", lane_axis=None)
     assert p.grid == (1, 1)
     assert p.placement == "data=1/1dev"     # PR 2 canonical string
     lane = Placement(mesh, batch_axis=None, lane_axis="data")
     assert lane.grid == (1, 1)
     assert lane.placement == "lane:data=1/1dev"   # never collides w/ batch
-    mesh2 = jax.make_mesh((1, 1), ("data", "lane"))
+    mesh2 = make_mesh((1, 1), ("data", "lane"))
     both = Placement(mesh2, batch_axis="data", lane_axis="lane")
     assert both.grid == (1, 1)
     assert both.placement == "data=1xlane=1/1dev"
@@ -126,7 +126,7 @@ def test_as_placement_normalization():
     p = as_placement(1)
     assert isinstance(p, Placement) and p.placement == "data=1/1dev"
     assert as_placement(p) is p
-    mesh = jax.make_mesh((1,), ("x",))
+    mesh = make_mesh((1,), ("x",))
     pm = as_placement(mesh, "x")
     assert pm.batch_axis == "x" and pm.lane_axis is None
     pt = as_placement((1, 1))
@@ -144,7 +144,7 @@ def test_run_suite_accepts_mesh_forms():
     s0 = run_suite(pats, backend="xla", runs=1, cache=cache, digest=True)
     d0 = [r.out_digest for r in s0.results]
     for mesh in (1, (1, 1), Placement.create(1),
-                 jax.make_mesh((1,), ("data",))):
+                 make_mesh((1,), ("data",))):
         s = run_suite(pats, backend="xla", runs=1, cache=cache, mesh=mesh,
                       digest=True)
         assert [r.out_digest for r in s.results] == d0
@@ -158,11 +158,11 @@ def test_engine_sharded_rejects_batch_placements():
     from repro.core import GSEngine
     p = make_pattern("UNIFORM:4:1", kind="gather", delta=4, count=16)
     eng = GSEngine(p)
-    batchy = Placement(jax.make_mesh((1,), ("data",)), batch_axis="data")
+    batchy = Placement(make_mesh((1,), ("data",)), batch_axis="data")
     with pytest.raises(ValueError, match="lane-only"):
         eng.sharded(batchy)
     # a lane-only Placement is accepted and matches the unsharded run
-    lane_only = Placement(jax.make_mesh((1,), ("l",)), batch_axis=None,
+    lane_only = Placement(make_mesh((1,), ("l",)), batch_axis=None,
                           lane_axis="l")
     fn, args = eng.sharded(lane_only)
     ref_fn, ref_args = eng.build()

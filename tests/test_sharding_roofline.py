@@ -150,8 +150,9 @@ MINI_DRYRUN = textwrap.dedent("""\
     from repro.optim import AdamWConfig
     from repro.runtime.train import assemble_train
     from repro.launch.roofline import analyze_module
+    from repro.core.compat import make_mesh
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = dataclasses.replace(get_smoke_config("llama3-8b"),
                               n_heads=4, n_kv_heads=4, d_model=64,
                               vocab=512, attn_chunk=16)

@@ -5,7 +5,6 @@ patterns that write the same row twice, on every execution path
 The sequential-scalar backend is the semantic oracle: a fori_loop of
 writes IS last-write-wins by construction, with no mask involved.
 """
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -13,6 +12,7 @@ import pytest
 from repro.core import (ExecutorCache, GSEngine, Pattern, SuitePlan,
                         execute_bucket, make_pattern)
 from repro.core import backends as B
+from repro.core.compat import make_mesh
 from repro.core.engine import make_host_buffers
 
 # delta < span: neighbouring gathers/scatters overlap -> duplicate writes.
@@ -58,7 +58,7 @@ def test_batched_bucket_store_bit_identical(backend):
 
 @pytest.mark.parametrize("backend", B.BACKENDS)
 def test_sharded_bucket_store_bit_identical(backend):
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     plan = SuitePlan.build(DUP_PATTERNS)
     for bucket in plan.buckets:
         outs = execute_bucket(plan, bucket, backend=backend, mode="store",

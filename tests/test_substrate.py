@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from repro.checkpoint import Checkpointer, CheckpointManager
+from repro.core.compat import make_mesh
 from repro.data import TokenPipeline
 from repro.optim import (AdamWConfig, adamw_update, clip_by_global_norm,
                          init_opt_state, int8_compress, int8_decompress,
@@ -81,9 +82,10 @@ def test_int8_roundtrip():
 
 def test_compressed_psum_shardmap():
     from repro.optim.grad_utils import compressed_psum
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+
+    from repro.core.compat import shard_map_unchecked
 
     def f(g):
         out, res = compressed_psum(g, "data")
@@ -91,7 +93,8 @@ def test_compressed_psum_shardmap():
 
     g = jnp.asarray(np.random.default_rng(1).standard_normal((8, 4)),
                     jnp.float32)
-    out, res = shard_map(f, mesh=mesh, in_specs=P(), out_specs=P())(g)
+    out, res = shard_map_unchecked(f, mesh=mesh, in_specs=P(),
+                                   out_specs=P())(g)
     np.testing.assert_allclose(np.asarray(out), np.asarray(g), atol=0.05)
     # error feedback residual equals quantization error
     np.testing.assert_allclose(np.asarray(g - out), np.asarray(res),
@@ -237,19 +240,20 @@ def test_elastic_restore_across_meshes():
         import os, tempfile
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import sys; sys.path.insert(0, %r)
+        from repro.core.compat import make_mesh
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint import Checkpointer
 
         tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
-        mesh_a = jax.make_mesh((8, 1), ("data", "model"))
+        mesh_a = make_mesh((8, 1), ("data", "model"))
         tree_a = jax.device_put(tree, {"w": NamedSharding(mesh_a,
                                                           P("data", None))})
         with tempfile.TemporaryDirectory() as d:
             ck = Checkpointer(d)
             ck.save(1, tree_a)
             # restore onto a DIFFERENT topology: 2x4, sharded other way
-            mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+            mesh_b = make_mesh((2, 4), ("data", "model"))
             sh_b = {"w": NamedSharding(mesh_b, P(None, "model"))}
             like = {"w": jax.ShapeDtypeStruct((8, 8), jnp.float32)}
             back = ck.restore(1, like, shardings=sh_b)

@@ -149,8 +149,7 @@ def test_iter_eqns_depth_guard_raises_not_undercounts():
 
 
 def test_find_dtype_eqns():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64():
         j64 = jax.make_jaxpr(lambda x: x * 2.0)(
             jnp.arange(4, dtype=jnp.float64))
     assert find_dtype_eqns(j64, "float64")
