@@ -61,6 +61,16 @@ def exec_key_str(key: ExecKey) -> str:
                     for f in dataclasses.fields(ExecKey))
 
 
+def _named_call(exported) -> Callable:
+    """``exported.call`` under the exported function's own name, so the
+    restored program's XLA module reads as the stored one did
+    (``jit_spatter_gather_pallas``) and not ``jit_call``."""
+    def call(*args):
+        return exported.call(*args)
+    call.__name__ = call.__qualname__ = exported.fun_name
+    return call
+
+
 class RestoredExecutable:
     """A deserialized AOT executable, marked with its provenance.
 
@@ -276,7 +286,7 @@ class DiskTier:
             return None
         try:
             exported = jax_export.deserialize(payload)
-            fn = jax.jit(exported.call)
+            fn = jax.jit(_named_call(exported))
         except Exception:
             self._quarantine(path)
             return None

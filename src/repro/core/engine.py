@@ -55,19 +55,30 @@ def make_host_buffers(pattern: Pattern, row_width: int, seed: int = 0):
     scatter payload (None for gathers), and ``keep`` the last-write-wins
     keep mask over ``abs_idx`` (None for gathers).  The mask is computed
     HERE, on the host, once per pattern — static-index preprocessing never
-    enters a timed executable (paper §3.5).  Both GSEngine and the suite
-    planner (plan.py) build their device buffers from this one function so
-    batched and per-pattern execution see bit-identical inputs.
+    enters a timed executable (paper §3.5).  GSEngine builds its device
+    buffers from this function and the suite planner (plan.py) from the
+    same draws (``draw_host_buffers``) and mask, so batched and
+    per-pattern execution see bit-identical inputs.
     """
+    src, abs_idx, vals = draw_host_buffers(pattern, row_width, seed)
+    if pattern.kind == "gather":
+        return src, abs_idx, None, None
+    return src, abs_idx, vals, B.keep_last_mask(abs_idx)
+
+
+def draw_host_buffers(pattern: Pattern, row_width: int, seed: int = 0):
+    """``make_host_buffers`` without the keep mask: (src, abs_idx, vals),
+    the seeded draws and the absolute indices.  The planner computes the
+    mask apart (store mode only), under its own span."""
     rng = np.random.default_rng(seed)
     f = pattern.footprint()
     abs_idx = pattern.absolute_indices().reshape(-1)
     src = rng.standard_normal((f, row_width), dtype=np.float32)
     if pattern.kind == "gather":
-        return src, abs_idx, None, None
+        return src, abs_idx, None
     vals = rng.standard_normal((abs_idx.shape[0], row_width),
                                dtype=np.float32)
-    return src, abs_idx, vals, B.keep_last_mask(abs_idx)
+    return src, abs_idx, vals
 
 
 @dataclasses.dataclass(frozen=True)

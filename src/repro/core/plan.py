@@ -70,10 +70,11 @@ table is a scratch row; padded lanes (both the lane tail up to N_pad and,
 for scatters, their payload) point at it, so they can never touch real
 rows, and they never enter the bandwidth numerator — ``measured_gbs`` /
 ``modeled_gbs`` keep exactly the paper's §3.5 useful-bytes formula.
-Per-pattern buffers come from ``engine.make_host_buffers`` — the same
-function ``GSEngine`` uses — so batched results are bit-identical to
-per-pattern execution (asserted by tests/test_suite_plan.py on all four
-backends, and by tests/test_sharded_plan.py for the sharded path).
+Per-pattern buffers come from ``engine.draw_host_buffers`` — the draws
+``GSEngine`` uses (``make_host_buffers``) — so batched results are
+bit-identical to per-pattern execution (asserted by
+tests/test_suite_plan.py on all four backends, and by
+tests/test_sharded_plan.py for the sharded path).
 
 Hot-path hygiene.  Store-mode scatter needs last-write-wins dedup; its
 keep mask is a pure function of the (static) padded index buffer, so
@@ -96,6 +97,7 @@ reports the bandwidth the launch achieved.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import math
 import threading
@@ -110,8 +112,9 @@ from jax.sharding import Mesh
 
 from . import backends as B
 from . import bandwidth as bw
-from .engine import SCATTER_MODES, RunResult, make_host_buffers
+from .engine import SCATTER_MODES, RunResult, draw_host_buffers
 from .pattern import Pattern
+from .spans import span
 
 
 def next_pow2(n: int) -> int:
@@ -622,6 +625,20 @@ def default_cache() -> ExecutorCache:
     return _DEFAULT_CACHE
 
 
+def program_name(backend: str, kind: str, mode: str) -> str:
+    """The bucket program's name, after what it runs: its XLA module (the
+    profiler's "XLA Modules" line) reads ``jit_<name>``, e.g.
+    ``jit_spatter_gather_pallas`` or ``jit_spatter_scatter_store_xla``."""
+    if kind == "gather":
+        return f"spatter_gather_{backend}"
+    return f"spatter_{kind}_{mode}_{backend}"
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 def _raw_batched_fn(backend: str, kind: str, mode: str) -> Callable:
     """The (unjitted) bucket op — single source of truth for the bucket
     executable's signature, shared by the single-device and sharded
@@ -635,7 +652,7 @@ def _raw_batched_fn(backend: str, kind: str, mode: str) -> Callable:
         def fn(dst_b, idx_b, vals_b, keep_b):
             return B.scatter_batched(dst_b, idx_b, vals_b, mode=mode,
                                      backend=backend, keep=keep_b)
-    return fn
+    return _named(fn, program_name(backend, kind, mode))
 
 
 def _build_executable(backend: str, kind: str, mode: str) -> Callable:
@@ -684,7 +701,7 @@ def _lane_body(kind: str, mode: str, lane_axis: str) -> Callable:
             covered = jax.lax.psum(cov_l, lane_axis)
             return jnp.where(covered > 0, jax.lax.psum(out_l, lane_axis),
                              dst_b)
-    return fn
+    return _named(fn, program_name("pallas", kind, mode))
 
 
 # ---------------------------------------------------------------------------
@@ -1113,8 +1130,12 @@ def _bucket_executable(cache: ExecutorCache, backend: str, spec: BucketSpec,
 def _assemble_members(spec: BucketSpec, patterns: Sequence[Pattern],
                       dtype, row_width: int, seeds: Sequence[int],
                       batch: int | None = None, mode: str = "store",
-                      lanes: int | None = None):
-    """Stack member patterns (of one bucket shape) into batched buffers.
+                      lanes: int | None = None,
+                      placement: Placement | None = None,
+                      spans: Callable = span):
+    """Stack member patterns (of one bucket shape) into batched buffers
+    and commit them to the device (to ``placement``'s shardings when
+    given), ready.
 
     Returns (args, real_lanes) where args feeds the bucket executable and
     real_lanes[b] is member b's un-padded lane count.  Table row F_pad is
@@ -1132,14 +1153,20 @@ def _assemble_members(spec: BucketSpec, patterns: Sequence[Pattern],
     why coalescing preserves bit-identity row by row.
 
     Scatter buckets also carry the (B_pad, N_pad) last-write-wins keep
-    mask for store mode: real lanes reuse the per-pattern mask
-    ``make_host_buffers`` already computed (real indices never reach the
-    scratch row F_pad, so padding can't change their dedup), and of the
-    padding lanes — which ALL point at F_pad — only each row's final lane
-    keeps, so the single-pass store kernel's at-most-one-write-per-row
-    contract holds for every row including scratch.  In add mode (and in
-    gather buckets) no mask is computed; the add executable's keep
-    operand is an all-False placeholder it never reads.
+    mask for store mode: real lanes take the per-pattern mask
+    (``backends.keep_last_mask`` over the member's own indices — real
+    indices never reach the scratch row F_pad, so padding can't change
+    their dedup), and of the padding lanes — which ALL point at F_pad —
+    only each row's final lane keeps, so the single-pass store kernel's
+    at-most-one-write-per-row contract holds for every row including
+    scratch.  In add mode (and in gather buckets) no mask is computed;
+    the add executable's keep operand is an all-False placeholder it
+    never reads.
+
+    ``spans`` makes each step's span (``core/spans.span`` with the
+    launch's sink and ids bound): ``plan.stack`` (the padded buffers and
+    the copies into them), ``plan.draw`` and ``plan.keep_mask`` per
+    member, and ``plan.to_device`` (the transfer, up to readiness).
     """
     nb = len(patterns)
     if len(seeds) != nb:
@@ -1151,45 +1178,60 @@ def _assemble_members(spec: BucketSpec, patterns: Sequence[Pattern],
     if n_pad < spec.idx_len:
         raise ValueError(f"lanes {n_pad} < bucket idx_len {spec.idx_len}")
     f_pad, r = spec.footprint, row_width
-    idx_b = np.full((b_pad, n_pad), f_pad, np.int32)       # pad -> scratch
-    table_b = (np.zeros((b_pad, f_pad + 1, r), np.float32)
-               if spec.kind == "gather" else None)
-    vals_b = (np.zeros((b_pad, n_pad, r), np.float32)
-              if spec.kind == "scatter" else None)
-    keep_b = (np.zeros((b_pad, n_pad), bool)
-              if spec.kind == "scatter" else None)
-    store = spec.kind == "scatter" and mode == "store"
-    if store:
-        keep_b[:, -1] = True       # scratch row's single write (pad lanes)
+    gather = spec.kind == "gather"
+    store = not gather and mode == "store"
+    with spans("plan.stack"):
+        idx_b = np.full((b_pad, n_pad), f_pad, np.int32)   # pad -> scratch
+        if gather:
+            host = (np.zeros((b_pad, f_pad + 1, r), np.float32), idx_b)
+        else:
+            host = (idx_b, np.zeros((b_pad, n_pad, r), np.float32),
+                    np.zeros((b_pad, n_pad), bool))
+            if store:
+                host[2][:, -1] = True   # scratch row's single write (pads)
     real_lanes = []
     for b, p in enumerate(patterns):
-        src, abs_idx, vals, keep = make_host_buffers(p, r, seed=seeds[b])
+        with spans("plan.draw", lanes=p.count * p.index_len):
+            src, abs_idx, vals = draw_host_buffers(p, r, seed=seeds[b])
         n = abs_idx.shape[0]
+        keep = None
+        if store:
+            with spans("plan.keep_mask", lanes=n):
+                keep = B.keep_last_mask(abs_idx)
         real_lanes.append(n)
-        idx_b[b, :n] = abs_idx
-        if spec.kind == "gather":
-            table_b[b, :src.shape[0]] = src
+        with spans("plan.stack"):
+            idx_b[b, :n] = abs_idx
+            if gather:
+                host[0][b, :src.shape[0]] = src
+            else:
+                host[1][b, :n] = vals
+                if store:
+                    host[2][b, :n] = keep   # n == n_pad overwrites the True
+    with spans("plan.to_device", bytes=sum(a.nbytes for a in host)):
+        if gather:
+            table_b, idx_b = host
+            args = (jnp.asarray(table_b, dtype), jnp.asarray(idx_b))
         else:
-            vals_b[b, :n] = vals
-            if store:
-                keep_b[b, :n] = keep      # n == n_pad overwrites the True
-    idx = jnp.asarray(idx_b)
-    if spec.kind == "gather":
-        return (jnp.asarray(table_b, dtype), idx), real_lanes
-    dst = jnp.zeros((b_pad, f_pad + 1, r), dtype)
-    return (dst, idx, jnp.asarray(vals_b, dtype),
-            jnp.asarray(keep_b)), real_lanes
+            idx_b, vals_b, keep_b = host
+            args = (jnp.zeros((b_pad, f_pad + 1, r), dtype),
+                    jnp.asarray(idx_b), jnp.asarray(vals_b, dtype),
+                    jnp.asarray(keep_b))
+        if placement is not None:
+            args = placement.place(spec.kind, args)
+        jax.block_until_ready(args)
+    return args, real_lanes
 
 
 def _assemble_bucket(plan: SuitePlan, bucket: Bucket, dtype, row_width: int,
                      seed: int, batch: int | None = None,
-                     mode: str = "store", lanes: int | None = None):
+                     mode: str = "store", lanes: int | None = None,
+                     placement: Placement | None = None):
     """One-plan wrapper over ``_assemble_members`` (one seed for all
     members — the serial ``run_plan`` regime)."""
     patterns = [plan.patterns[pos] for pos in bucket.members]
     return _assemble_members(bucket.spec, patterns, dtype, row_width,
                              [seed] * len(patterns), batch=batch,
-                             mode=mode, lanes=lanes)
+                             mode=mode, lanes=lanes, placement=placement)
 
 
 def execute_bucket(plan: SuitePlan, bucket: Bucket, *, backend: str = "xla",
@@ -1216,9 +1258,8 @@ def execute_bucket(plan: SuitePlan, bucket: Bucket, *, backend: str = "xla",
                                           row_width, mode,
                                           len(bucket.members), placement)
     args, real_lanes = _assemble_bucket(plan, bucket, dtype, row_width, seed,
-                                        batch=batch, mode=mode, lanes=lanes)
-    if placement is not None:
-        args = placement.place(spec.kind, args)
+                                        batch=batch, mode=mode, lanes=lanes,
+                                        placement=placement)
     out = np.asarray(jax.block_until_ready(fn(*args)))
     trimmed = []
     for b, pos in enumerate(bucket.members):
@@ -1305,6 +1346,12 @@ class LaunchResult:
     served by the xla fallback after the requested backend's builder
     failed (DESIGN.md §14) — set on EVERY launch of a degraded
     executable, not only the one that fell back.
+
+    ``phases_ms`` sums the launch's spans by name, in ms (``plan.draw``,
+    ``plan.keep_mask``, ``plan.stack``, ``plan.to_device``,
+    ``plan.warmup``, ``plan.runs``, ``plan.fetch``; ``demux`` adds
+    ``plan.digest``).  ``span_ids`` are the stats every span of the
+    launch carries (the scheduler's ``launch`` and ``reqs``).
     """
     key: ExecKey                      # the key actually served (best_batch)
     t_bucket: float                   # min over runs (paper §3.5)
@@ -1315,6 +1362,8 @@ class LaunchResult:
     out: np.ndarray | None            # batched output (digest launches)
     compiled: bool
     degraded: bool = False
+    phases_ms: dict = dataclasses.field(default_factory=dict)
+    span_ids: dict = dataclasses.field(default_factory=dict)
 
 
 def make_work(plan: SuitePlan, *, backend: str = "xla", dtype=None,
@@ -1356,7 +1405,8 @@ def make_work(plan: SuitePlan, *, backend: str = "xla", dtype=None,
 
 
 def launch(works: Sequence[BucketWork],
-           cache: ExecutorCache | None = None) -> LaunchResult:
+           cache: ExecutorCache | None = None, *,
+           span_ids: dict | None = None) -> LaunchResult:
     """Execute one (possibly coalesced) bucket launch: the pure step.
 
     ``works`` is one or more work units sharing a ``family`` and ``runs``
@@ -1370,6 +1420,9 @@ def launch(works: Sequence[BucketWork],
     then ``runs`` timed executions (fresh zeroed dst per run for
     scatters), min-over-K.  The batched output is pulled to the host
     only when some unit wants digests.
+
+    Every step runs under its own span (``core/spans.py``), carrying
+    ``span_ids`` and summed into ``LaunchResult.phases_ms``.
     """
     if not works:
         raise ValueError("launch needs at least one work unit")
@@ -1397,38 +1450,46 @@ def launch(works: Sequence[BucketWork],
     batch, lanes = served.batch, pad_lanes(spec.idx_len, l_shards)
     patterns = [p for w in works for p in w.patterns]
     seeds = [w.seed for w in works for _ in w.patterns]
+    span_ids = dict(span_ids or {})
+    phases_ms: dict = {}
+    spans = functools.partial(span, sink=phases_ms, **span_ids)
     args, real_lanes = _assemble_members(spec, patterns, dtype,
                                          w0.row_width, seeds, batch=batch,
-                                         mode=w0.mode, lanes=lanes)
-    if placement is not None:
-        args = placement.place(spec.kind, args)
+                                         mode=w0.mode, lanes=lanes,
+                                         placement=placement, spans=spans)
+    times = []
     if spec.kind == "scatter":
         dst, idx, vals, keep = args
-        jax.block_until_ready(fn(dst, idx, vals, keep))    # compile & warm
-        times = []
-        for _ in range(runs):
-            d = jnp.zeros_like(dst)
-            if placement is not None:
-                d = placement.place(spec.kind, (d,))[0]
-            jax.block_until_ready(d)
-            t0 = time.perf_counter()
-            out = fn(d, idx, vals, keep)
-            jax.block_until_ready(out)
-            times.append(time.perf_counter() - t0)
+        with spans("plan.warmup"):
+            jax.block_until_ready(fn(dst, idx, vals, keep))
+        with spans("plan.runs"):
+            for _ in range(runs):
+                d = jnp.zeros_like(dst)
+                if placement is not None:
+                    d = placement.place(spec.kind, (d,))[0]
+                jax.block_until_ready(d)
+                t0 = time.perf_counter()
+                out = fn(d, idx, vals, keep)
+                jax.block_until_ready(out)
+                times.append(time.perf_counter() - t0)
     else:
-        jax.block_until_ready(fn(*args))                   # compile & warm
-        times = []
-        for _ in range(runs):
-            t0 = time.perf_counter()
-            out = fn(*args)
-            jax.block_until_ready(out)
-            times.append(time.perf_counter() - t0)
-    want_out = any(w.digest for w in works)
+        with spans("plan.warmup"):
+            jax.block_until_ready(fn(*args))
+        with spans("plan.runs"):
+            for _ in range(runs):
+                t0 = time.perf_counter()
+                out = fn(*args)
+                jax.block_until_ready(out)
+                times.append(time.perf_counter() - t0)
+    host_out = None
+    if any(w.digest for w in works):
+        with spans("plan.fetch", bytes=out.nbytes):
+            host_out = np.asarray(out)
     return LaunchResult(key=served, t_bucket=min(times),   # paper §3.5
                         batch=batch, lanes=lanes, n_members=n_members,
-                        real_lanes=tuple(real_lanes),
-                        out=np.asarray(out) if want_out else None,
-                        compiled=compiled, degraded=degraded)
+                        real_lanes=tuple(real_lanes), out=host_out,
+                        compiled=compiled, degraded=degraded,
+                        phases_ms=phases_ms, span_ids=span_ids)
 
 
 def demux(result: LaunchResult, work: BucketWork,
@@ -1447,34 +1508,39 @@ def demux(result: LaunchResult, work: BucketWork,
     member reports the bandwidth the launch achieved on its share.
     Digests hash the member's trimmed rows only, so they are a pure
     function of (pattern, seed, mode, dtype) — bit-identical between
-    solo and coalesced launches.
+    solo and coalesced launches.  The work runs under a ``plan.digest``
+    span with the launch's ids, summed into ``result.phases_ms``.
     """
     spec = work.spec
     dtype = jnp.dtype(work.dtype)
     elem_bytes = dtype.itemsize * work.row_width
     total_lanes = (sum(result.real_lanes)
                    + (result.batch - result.n_members) * result.lanes)
+    rows = [offset + i for i in range(work.n_members)]
+    trims = ([result.out[b, :result.real_lanes[b]] if spec.kind == "gather"
+              else result.out[b, :p.footprint()]
+              for b, p in zip(rows, work.patterns)]
+             if work.digest else [None] * work.n_members)
     out: list[tuple[int, RunResult]] = []
-    for i, pos in enumerate(work.positions):
-        b = offset + i
-        p = work.patterns[i]
-        t_i = result.t_bucket * result.real_lanes[b] / total_lanes
-        tm = bw.tpu_tile_model(p, elem_bytes)
-        dg = None
-        if work.digest:
-            trim = (result.out[b, :result.real_lanes[b]]
-                    if spec.kind == "gather"
-                    else result.out[b, :p.footprint()])
-            dg = hashlib.sha256(
-                np.ascontiguousarray(trim).tobytes()).hexdigest()
-        out.append((pos, RunResult(
-            pattern=p, backend=work.backend, elem_bytes=elem_bytes,
-            row_width=work.row_width, runs=work.runs, time_s=t_i,
-            measured_gbs=bw.paper_bandwidth(p, t_i, elem_bytes) / 1e9,
-            modeled_gbs=tm.modeled_gbs,
-            tile_efficiency=tm.tile_efficiency,
-            out_digest=dg,
-        )))
+    with span("plan.digest", result.phases_ms,
+              bytes=sum(t.nbytes for t in trims if t is not None),
+              **result.span_ids):
+        for b, pos, p, trim in zip(rows, work.positions, work.patterns,
+                                   trims):
+            t_i = result.t_bucket * result.real_lanes[b] / total_lanes
+            tm = bw.tpu_tile_model(p, elem_bytes)
+            dg = None
+            if trim is not None:
+                dg = hashlib.sha256(
+                    np.ascontiguousarray(trim).tobytes()).hexdigest()
+            out.append((pos, RunResult(
+                pattern=p, backend=work.backend, elem_bytes=elem_bytes,
+                row_width=work.row_width, runs=work.runs, time_s=t_i,
+                measured_gbs=bw.paper_bandwidth(p, t_i, elem_bytes) / 1e9,
+                modeled_gbs=tm.modeled_gbs,
+                tile_efficiency=tm.tile_efficiency,
+                out_digest=dg,
+            )))
     return out
 
 

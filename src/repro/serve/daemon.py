@@ -67,10 +67,16 @@ retains the PR 4 serialized path — one run lock, stats-snapshot deltas —
 as the scheduling baseline ``benchmarks/bench_serve.py`` measures
 against.  The cache itself is additionally lock-protected
 (plan.ExecutorCache) so /cache and /healthz can read counters mid-run.
+
+Every POST gets a ``request_id`` (returned by /run); the handler's work
+on it runs under flat ``spatterd.parse``/``spatterd.plan``/
+``spatterd.respond`` spans, and its launches under ``plan.*`` spans,
+on the JAX profiler's clock (core/spans.py, DESIGN.md §13).
 """
 from __future__ import annotations
 
 import argparse
+import itertools
 import json
 import signal
 import threading
@@ -79,6 +85,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 from repro.core import backends as B
 from repro.core.plan import ExecutorCache, SuitePlan, default_cache, make_work
+from repro.core.spans import span
 from repro.core.suite import aggregate_stats, run_suite, stream_reference
 
 from .faults import ENV_SPEC, FaultInjector
@@ -123,6 +130,7 @@ class SpatterDaemon:
         self.quiet = quiet
         self.started_at = time.time()
         self.n_requests = 0
+        self._request_ids = itertools.count(1)   # each POST's request_id
         self.faults = faults
         if faults is not None and self.cache.fault_hook is None:
             self.cache.fault_hook = faults.check
@@ -228,6 +236,11 @@ class SpatterDaemon:
         self.stop()
 
     # -- request execution ---------------------------------------------------
+    def next_request_id(self) -> int:
+        """A fresh request id: the response's ``request_id`` and the
+        ``req=`` of the request's spans."""
+        return next(self._request_ids)
+
     def _placement(self, mesh, axis: str):
         """Placement per (shape, batch axis), memoized by shape tuple: the
         canonical placement string — not the Mesh object's identity — keys
@@ -303,8 +316,15 @@ class SpatterDaemon:
                 _bounded_put(self._stream_refs, skey, ref)
         return ref
 
-    def run_request(self, req: SuiteRequest) -> dict:
+    def run_request(self, req: SuiteRequest,
+                    request_id: int | None = None) -> dict:
         """Execute one validated request; returns the response document.
+
+        ``request_id`` (a fresh one when None) is returned in the
+        response and carried by the request's spans: ``spatterd.parse``
+        (pattern build, mesh), ``spatterd.plan`` (plan, work units,
+        submit), ``spatterd.respond`` (stats, response), and the
+        ``plan.*`` spans of the launches that serve it (``reqs=``).
 
         Raises ValueError for request-shaped problems (bad pattern entry,
         mesh larger than the device count) — the handler maps those to
@@ -316,19 +336,24 @@ class SpatterDaemon:
         # suite cold while its warm executables are still deserializing
         # would break the warm-restart misses==0 proof
         self._ready.wait(TICKET_TIMEOUT_S)
+        if request_id is None:
+            request_id = self.next_request_id()
         # request-shaped failures (bad patterns, oversized mesh) resolve
         # BEFORE any queueing: a 400 never occupies a queue slot
-        patterns = req.build_patterns()
-        mesh = self._resolve_mesh(req, patterns)
+        with span("spatterd.parse", req=request_id):
+            patterns = req.build_patterns()
+            mesh = self._resolve_mesh(req, patterns)
         if self.scheduler is None:
             doc = self._run_serial(req, patterns, mesh)
         else:
-            doc = self._run_scheduled(req, patterns, mesh)
+            doc = self._run_scheduled(req, patterns, mesh, request_id)
+        doc["request_id"] = request_id
         with self._state_lock:
             self.n_requests += 1
         return doc
 
-    def _run_scheduled(self, req: SuiteRequest, patterns, mesh) -> dict:
+    def _run_scheduled(self, req: SuiteRequest, patterns, mesh,
+                       request_id: int) -> dict:
         """Submit the request's work units to the scheduler and wait.
 
         ``elapsed_s`` covers submit -> resolve, so it INCLUDES queue
@@ -347,17 +372,20 @@ class SpatterDaemon:
         """
         t0 = time.perf_counter()
         stream_ref = self._stream_ref_for(req) if req.stream_r else None
-        plan = SuitePlan.build(patterns)
-        if isinstance(mesh, str):          # "auto": per-bucket cost model
-            from repro.core.plan import auto_placements
-            mesh = auto_placements(plan, mesh, mesh_axis=req.mesh_axis,
-                                   backend=req.backend,
-                                   row_width=req.row_width)
-        works = make_work(plan, backend=req.backend, runs=req.runs,
-                          row_width=req.row_width, mode=req.mode,
-                          seed=req.seed, placement=mesh, digest=req.digest)
         deadline_s = req.deadline_ms / 1e3 if req.deadline_ms else None
-        ticket = self.scheduler.submit(works, deadline_s=deadline_s)
+        with span("spatterd.plan", req=request_id):
+            plan = SuitePlan.build(patterns)
+            if isinstance(mesh, str):          # "auto": per-bucket costs
+                from repro.core.plan import auto_placements
+                mesh = auto_placements(plan, mesh, mesh_axis=req.mesh_axis,
+                                       backend=req.backend,
+                                       row_width=req.row_width)
+            works = make_work(plan, backend=req.backend, runs=req.runs,
+                              row_width=req.row_width, mode=req.mode,
+                              seed=req.seed, placement=mesh,
+                              digest=req.digest)
+            ticket = self.scheduler.submit(works, deadline_s=deadline_s,
+                                           request_id=request_id)
         wait_s = (TICKET_TIMEOUT_S if deadline_s is None
                   else min(TICKET_TIMEOUT_S, deadline_s + DEADLINE_GRACE_S))
         try:
@@ -369,13 +397,14 @@ class SpatterDaemon:
                     f"deadline_ms={req.deadline_ms} expired before the "
                     f"request's work launched") from None
             raise
-        results = [ticket.results[i] for i in range(len(patterns))]
-        stats = aggregate_stats(results, metric=req.metric, plan=plan,
-                                stream_ref=stream_ref)
-        return self._response(req, stats, mesh,
-                              hits=ticket.hits, misses=ticket.misses,
-                              serve=ticket.telemetry(),
-                              elapsed_s=time.perf_counter() - t0)
+        with span("spatterd.respond", req=request_id):
+            results = [ticket.results[i] for i in range(len(patterns))]
+            stats = aggregate_stats(results, metric=req.metric, plan=plan,
+                                    stream_ref=stream_ref)
+            return self._response(req, stats, mesh,
+                                  hits=ticket.hits, misses=ticket.misses,
+                                  serve=ticket.telemetry(),
+                                  elapsed_s=time.perf_counter() - t0)
 
     def _run_serial(self, req: SuiteRequest, patterns, mesh) -> dict:
         """PR 4 baseline path (``workers=0``): one run lock, telemetry
@@ -445,8 +474,8 @@ class SpatterDaemon:
                 # model's choices, in bucket order
                 "placement": placement,
             },
-            # scheduler telemetry: queued_ms, launches, coalesced_launches
-            # (null on the workers=0 baseline path)
+            # scheduler telemetry: queued_ms, launches, coalesced_launches,
+            # phases_ms (null on the workers=0 baseline path)
             "serve": serve,
             "elapsed_s": elapsed_s,
         }
@@ -608,7 +637,16 @@ def _make_handler(daemon: SpatterDaemon):
             daemon._log(fmt, *args)
 
         def _reply(self, code: int, doc: dict,
-                   headers: dict | None = None) -> None:
+                   headers: dict | None = None,
+                   request_id: int | None = None) -> None:
+            """Send ``doc``; a request's reply (``request_id`` given) runs
+            under its ``spatterd.respond`` span."""
+            if request_id is None:
+                return self._send(code, doc, headers)
+            with span("spatterd.respond", req=request_id):
+                self._send(code, doc, headers)
+
+        def _send(self, code: int, doc: dict, headers: dict | None) -> None:
             body = json.dumps(doc).encode()
             self.send_response(code)
             self.send_header("Content-Type", "application/json")
@@ -672,17 +710,20 @@ def _make_handler(daemon: SpatterDaemon):
                                   "error": f"no such path {self.path!r}; "
                                            f"POST /run or /warm"})
                 return
+            rid = daemon.next_request_id()
             try:
-                doc = json.loads(body)
-                req = SuiteRequest.from_json(doc)
+                with span("spatterd.parse", req=rid):
+                    req = SuiteRequest.from_json(json.loads(body))
             except (ValueError, KeyError, TypeError) as e:
-                self._reply(400, {"ok": False, "error": f"bad request: {e}"})
+                self._reply(400, {"ok": False, "error": f"bad request: {e}"},
+                            request_id=rid)
                 return
             try:
                 if self.path == "/warm":
-                    self._reply(200, daemon.warm(req))
+                    doc = daemon.warm(req)
                 else:
-                    self._reply(200, daemon.run_request(req))
+                    doc = daemon.run_request(req, request_id=rid)
+                self._reply(200, doc, request_id=rid)
             except (QueueFull, SchedulerStopped) as e:
                 # backpressure, decided BEFORE the run touched a queue
                 # slot: the client should retry, not fail — Retry-After
@@ -691,17 +732,21 @@ def _make_handler(daemon: SpatterDaemon):
                     1, round(e.depth / max(1, e.limit) * 5))
                 self._reply(503, {"ok": False, "error": str(e),
                                   "retry_after_s": retry},
-                            headers={"Retry-After": str(retry)})
+                            headers={"Retry-After": str(retry)},
+                            request_id=rid)
             except DeadlineExceeded as e:
                 # the request's own deadline_ms expired in-queue: the
                 # expired work never launched (scheduler contract)
                 self._reply(504, {"ok": False, "error": str(e),
-                                  "deadline_ms": req.deadline_ms})
+                                  "deadline_ms": req.deadline_ms},
+                            request_id=rid)
             except ValueError as e:
-                self._reply(400, {"ok": False, "error": str(e)})
+                self._reply(400, {"ok": False, "error": str(e)},
+                            request_id=rid)
             except Exception as e:   # execution failure: report, stay alive
                 self._reply(500, {"ok": False,
-                                  "error": f"{type(e).__name__}: {e}"})
+                                  "error": f"{type(e).__name__}: {e}"},
+                            request_id=rid)
 
     return Handler
 

@@ -35,7 +35,10 @@ replaces the lock with a scheduler over the planner's work-unit pipeline
   under concurrency.  Non-leader participants record a hit (their
   bucket ran warm on a shared launch).  Per-ticket ``queued_ms`` (worst
   item wait) and ``coalesced_launches`` make the scheduling itself
-  observable.
+  observable.  ``phases_ms`` carries each launch's host phases
+  (``LaunchResult.phases_ms``: the ``plan.*`` spans, in ms) onto every
+  ticket the launch served, summed over its launches; a coalesced
+  launch's phases are shared, each rider sees the whole launch's.
 
 Scheduling policy is FIFO with *bounded bucket-affinity bypass*: the
 leader is always the oldest queued item, and a swept item only ever
@@ -72,6 +75,7 @@ is gone) so workers never launch work nobody will read.
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import deque
@@ -161,9 +165,15 @@ class SuiteTicket:
     counts launches this request rode that were served by a degraded
     (fallback-built) executable — threaded from
     ``LaunchResult.degraded`` so per-request telemetry shows it.
+
+    ``request_id`` (the daemon's) rides on the spans of every launch
+    that serves the ticket (``reqs=``); ``phases_ms`` sums those
+    launches' ``LaunchResult.phases_ms`` by span name — a coalesced
+    launch's phases are shared, each ticket it served sees them whole.
     """
 
-    def __init__(self, n_works: int, deadline: float | None = None):
+    def __init__(self, n_works: int, deadline: float | None = None,
+                 request_id: int | None = None):
         self.results: dict[int, object] = {}
         self.hits = 0
         self.misses = 0
@@ -171,7 +181,9 @@ class SuiteTicket:
         self.coalesced_launches = 0
         self.degraded_launches = 0
         self.queued_ms = 0.0
+        self.phases_ms: dict[str, float] = {}
         self.deadline = deadline
+        self.request_id = request_id
         self.error: BaseException | None = None
         self.done = threading.Event()
         self._pending = n_works
@@ -192,6 +204,7 @@ class SuiteTicket:
             "coalesced_launches": self.coalesced_launches,
             "degraded_launches": self.degraded_launches,
             "queued_ms": self.queued_ms,
+            "phases_ms": dict(self.phases_ms),
         }
 
 
@@ -233,6 +246,7 @@ class Scheduler:
         self.total_launches = 0
         self.coalesced_launches = 0
         self.degraded_launches = 0
+        self._launch_ids = itertools.count(1)   # the spans' launch=<n>
         self._threads = [
             threading.Thread(target=self._run_worker,
                              name=f"spatterd-worker-{i}", daemon=True)
@@ -245,7 +259,8 @@ class Scheduler:
 
     # -- submission ----------------------------------------------------------
     def submit(self, works: list[BucketWork], *,
-               deadline_s: float | None = None) -> SuiteTicket:
+               deadline_s: float | None = None,
+               request_id: int | None = None) -> SuiteTicket:
         """Enqueue one request's work units; returns its ticket.
 
         Raises ``QueueFull`` (backpressure) or ``SchedulerStopped``
@@ -258,12 +273,14 @@ class Scheduler:
         ``DeadlineExceeded`` — they never launch.  Work already
         in-flight at expiry finishes (a JAX execution cannot be
         cancelled midway); its result is discarded by the failed ticket.
+        ``request_id`` names the request on its launches' spans.
         """
         if not works:
             raise ValueError("submit needs at least one work unit")
         ticket = SuiteTicket(len(works),
                              deadline=(time.monotonic() + deadline_s
-                                       if deadline_s is not None else None))
+                                       if deadline_s is not None else None),
+                             request_id=request_id)
         items = [_Item(ticket, w) for w in works]
         with self._cv:
             if self._stopping:
@@ -402,12 +419,18 @@ class Scheduler:
         t_start = time.perf_counter()
         works = [it.work for it in batch]
         family = batch[0].key[0]
+        span_ids = {"launch": next(self._launch_ids)}
+        reqs = dict.fromkeys(it.ticket.request_id for it in batch
+                             if it.ticket.request_id is not None)
+        if reqs:
+            # ";" because the profiler splits a span's stats at ","
+            span_ids["reqs"] = ";".join(map(str, reqs))
         try:
             # the launch fault site: injected exceptions/latency land
             # exactly where a real launch failure would
             if self._faults is not None:
                 self._faults.check("launch")
-            result = launch(works, self.cache)
+            result = launch(works, self.cache, span_ids=span_ids)
             demuxed, offset = [], 0
             for it in batch:
                 demuxed.append(demux(result, it.work, offset))
@@ -449,6 +472,8 @@ class Scheduler:
                     t.hits += 1
                 t.queued_ms = max(t.queued_ms,
                                   (t_start - it.t_enq) * 1e3)
+                for name, ms in result.phases_ms.items():
+                    t.phases_ms[name] = t.phases_ms.get(name, 0.0) + ms
                 self._finish_locked(it)
 
     # -- control plane -------------------------------------------------------

@@ -30,8 +30,13 @@ Responses: a 200 carries ``stats`` (SuiteStats.to_json), ``cache``
 (per-request hits/misses — misses is an EXACT compile count, attributed
 per launch by the scheduler — plus lifetime counters), ``plan``
 (n_buckets/pad_waste), ``serve`` (scheduler telemetry: ``queued_ms``,
-``launches``, ``coalesced_launches``; null on a workers=0 daemon), and
-``elapsed_s``.  When the scheduler's bounded queue is full the daemon
+``launches``, ``coalesced_launches``, and ``phases_ms`` — the host
+phases of the launches that served the request, ms by span name:
+``plan.draw``, ``plan.keep_mask``, ``plan.stack``, ``plan.to_device``,
+``plan.warmup``, ``plan.runs``, ``plan.fetch``, ``plan.digest``; a
+coalesced launch's phases are shared by every request it served; null
+on a workers=0 daemon), ``elapsed_s``, and ``request_id`` (the daemon's
+id for the request, also the ``req=`` of its profiler spans).  When the scheduler's bounded queue is full the daemon
 answers **503 with a Retry-After header** and a ``retry_after_s`` field
 — backpressure decided before the request costs anything; clients should
 back off and retry the identical request (DESIGN.md §13).

@@ -162,3 +162,19 @@ def test_key_str_covers_every_field():
     for field in ("backend", "kind", "idx_len", "batch", "dtype",
                   "placement"):
         assert f"{field}=" in s
+
+
+def test_restored_program_keeps_its_name(tmp_path):
+    """A restored executable runs as an exported call; its XLA module
+    still reads as the stored program's, not ``jit_call``."""
+    from repro.core.plan import key_avals
+    tier = DiskTier(str(tmp_path))
+    units = enumerate_executables(PLAN)
+    for key, builder, _ in units:
+        avals = key_avals(key)
+        assert tier.store(key, builder(), avals)
+        restored = tier.load(key)
+        text = restored._fn.lower(*avals).as_text()
+        want = ("spatter_gather_xla" if key.kind == "gather"
+                else f"spatter_scatter_{key.mode}_xla")
+        assert text.startswith(f"module @jit_{want} "), text[:80]

@@ -97,6 +97,41 @@ def test_identical_concurrent_requests_one_compile_fewer_launches():
         assert _ticket_digests(t, len(ref)) == ref
 
 
+def test_coalesced_launch_shares_its_phases_and_names_its_requests(
+        tmp_path):
+    """Both tickets of one coalesced launch carry its whole phases; the
+    launch's spans carry ``launch=`` and both request ids."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    sched = Scheduler(ExecutorCache(), workers=1)
+    try:
+        sched.pause()
+        tickets = [sched.submit(make_work(SINGLE, runs=2, digest=True),
+                                request_id=rid) for rid in (41, 42)]
+        with jax.profiler.trace(str(tmp_path)):
+            sched.resume()
+            for t in tickets:
+                t.wait(timeout=300)
+    finally:
+        sched.stop()
+    assert sched.snapshot()["total_launches"] == 1
+    a, b = (t.telemetry()["phases_ms"] for t in tickets)
+    assert a == b
+    assert set(a) == {"plan.draw", "plan.stack", "plan.to_device",
+                      "plan.warmup", "plan.runs", "plan.fetch",
+                      "plan.digest"}
+    assert all(ms > 0 for ms in a.values())
+    (xplane,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+    stats = [dict(e.stats) for plane in ProfileData.from_file(xplane).planes
+             for line in plane.lines for e in line.events
+             if e.name.startswith("plan.")]
+    assert stats and all(s["launch"] == stats[0]["launch"] for s in stats)
+    assert {s["reqs"] for s in stats} == {"41;42"}
+
+
 def test_mixed_suite_concurrency_matches_serial_digests():
     cache = ExecutorCache()
     sched = Scheduler(cache, workers=2)

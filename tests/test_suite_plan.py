@@ -4,6 +4,7 @@ import random
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import (BucketSpec, ExecutorCache, GSEngine, Pattern,
                         SuitePlan, execute_bucket, make_pattern, run_plan,
@@ -291,3 +292,25 @@ def test_run_plan_digest_deterministic_across_caches():
     # digest off by default: results carry None
     r3 = run_plan(plan, runs=1, cache=ExecutorCache())
     assert all(r.out_digest is None for r in r3)
+
+
+@pytest.mark.parametrize("backend,kind,mode,placed,name", [
+    ("xla", "gather", "", False, "spatter_gather_xla"),
+    ("xla", "scatter", "store", False, "spatter_scatter_store_xla"),
+    ("pallas", "gather", "", True, "spatter_gather_pallas"),
+    ("pallas", "scatter", "add", True, "spatter_scatter_add_pallas"),
+])
+def test_bucket_program_is_named_after_what_it_runs(backend, kind, mode,
+                                                    placed, name):
+    """The bucket program's XLA module, which the profiler's "XLA Modules"
+    line shows, reads ``jit_<kind>[_<mode>]_<backend>`` on the plain jit
+    and on the shard_map path (a pallas placement) alike."""
+    from repro.core.plan import (Placement, _lane_body, bucket_avals,
+                                 bucket_builder)
+    spec = BucketSpec(kind=kind, idx_len=128, footprint=256)
+    placement = Placement.create(1) if placed else None
+    fn = bucket_builder(backend, spec, mode, placement)()
+    lowered = fn.lower(*bucket_avals(spec, 1, 128, jnp.float32, 1))
+    assert lowered.as_text().startswith(f"module @jit_{name} ")
+    if placed:
+        assert _lane_body(kind, mode, "lane").__name__ == name
