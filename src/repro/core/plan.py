@@ -114,7 +114,7 @@ from . import backends as B
 from . import bandwidth as bw
 from .engine import SCATTER_MODES, RunResult, draw_host_buffers
 from .pattern import Pattern
-from .spans import span
+from .spans import profiling, span
 
 
 def next_pow2(n: int) -> int:
@@ -1422,7 +1422,10 @@ def launch(works: Sequence[BucketWork],
     only when some unit wants digests.
 
     Every step runs under its own span (``core/spans.py``), carrying
-    ``span_ids`` and summed into ``LaunchResult.phases_ms``.
+    ``span_ids`` and summed into ``LaunchResult.phases_ms``.  Under a
+    profiler a pallas gather also counts its window steps (DESIGN.md
+    §2.4) in a ``plan.window`` span, outside ``phases_ms``, and tags
+    ``plan.runs`` with them.
     """
     if not works:
         raise ValueError("launch needs at least one work unit")
@@ -1475,7 +1478,12 @@ def launch(works: Sequence[BucketWork],
     else:
         with spans("plan.warmup"):
             jax.block_until_ready(fn(*args))
-        with spans("plan.runs"):
+        window = {}
+        if w0.backend == "pallas" and not degraded and profiling():
+            with span("plan.window", **span_ids):
+                window["window"] = _gather_windows(*args, w0.row_width,
+                                                   dtype, l_shards)
+        with spans("plan.runs", **window):
             for _ in range(runs):
                 t0 = time.perf_counter()
                 out = fn(*args)
@@ -1490,6 +1498,19 @@ def launch(works: Sequence[BucketWork],
                         real_lanes=tuple(real_lanes), out=host_out,
                         compiled=compiled, degraded=degraded,
                         phases_ms=phases_ms, span_ids=span_ids)
+
+
+def _gather_windows(table, idx, row_width: int, dtype, l_shards: int) -> str:
+    """``<window steps>;<grid steps>`` of a pallas gather launch
+    (``gather_rows.ops.window_steps``), each lane shard the launch its
+    device runs.  Read back from the device: profiled launches only."""
+    from repro.kernels.gather_rows import ops as gather_ops
+    idx = np.asarray(idx)
+    b, n = idx.shape
+    hits, steps = gather_ops.window_steps(
+        idx.reshape(b * l_shards, n // l_shards), table.shape[1], row_width,
+        dtype=jnp.dtype(dtype).name)
+    return f"{hits};{steps}"
 
 
 def demux(result: LaunchResult, work: BucketWork,

@@ -21,7 +21,7 @@ from time import perf_counter
 
 from jax.profiler import TraceAnnotation
 
-_profiling = TraceAnnotation.is_enabled
+profiling = TraceAnnotation.is_enabled     # () -> bool: a profiler is on
 
 
 class span:
@@ -36,7 +36,7 @@ class span:
         self._annotation = None
 
     def __enter__(self) -> "span":
-        if _profiling():
+        if profiling():
             self._annotation = TraceAnnotation(self._name, **self._ids)
             self._annotation.__enter__()
         self._t0 = perf_counter()

@@ -12,6 +12,8 @@ the CPU here; the ``mosaic`` fixture steers them to the compiled path for
 these tests only.  The persistent compilation cache is off around the
 compiles: entries written for a described chip cannot be read back.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -84,6 +86,37 @@ def test_gather_compiles(mosaic, one_chip, v, d, mode):
     c = _compile(lambda t, i: gops.gather_rows_batched(t, i, mode=mode),
                  table, idx)
     _mosaic_text(c)
+
+
+def _kernel_conds(jaxpr, inside=False) -> list[int]:
+    """Branch counts of the conds inside the Pallas kernels of ``jaxpr``."""
+    found = []
+    for eqn in jaxpr.eqns:
+        kernel = inside or eqn.primitive.name == "pallas_call"
+        if kernel and eqn.primitive.name == "cond":
+            found.append(len(eqn.params["branches"]))
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _kernel_conds(sub, kernel)
+    return found
+
+
+# (rows, width): LULESH-G7's table (2^26 + 1 rows) at width 1; width 128
+# has no window path
+@pytest.mark.parametrize("v,d", [((1 << 26) + 1, 1), (1 << 16, 128)])
+def test_dma_gather_window_path_compiles(mosaic, one_chip, v, d):
+    """Narrow rows in HBM: each grid step chooses between one window DMA
+    and one DMA per line, so the kernel holds one two-branch cond."""
+    n = 1 << 24 if d == 1 else 3000
+    fn = functools.partial(gops.gather_rows_batched, mode="dma")
+    table = _sds((1, v, d), jnp.float32, one_chip)
+    idx = _sds((1, n), jnp.int32, one_chip)
+    _mosaic_text(_compile(fn, table, idx))
+    jaxpr = jax.make_jaxpr(fn)(jax.ShapeDtypeStruct((1, v, d), jnp.float32),
+                               jax.ShapeDtypeStruct((1, n), jnp.int32))
+    assert _kernel_conds(jaxpr.jaxpr) == ([2] if d == 1 else [])
 
 
 def test_gather_auto_regime_from_tiled_bytes(mosaic, one_chip):

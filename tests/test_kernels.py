@@ -244,6 +244,145 @@ class TestGatherMultiRowBlocking:
             np.testing.assert_array_equal(np.asarray(out), ref)
 
 
+def _spatter(index, delta: int, count: int) -> np.ndarray:
+    """A Spatter pattern's absolute indices, ``delta * i + index[j]``,
+    i-major (``core/pattern.py``)."""
+    return (np.arange(count)[:, None] * delta
+            + np.asarray(index)[None, :]).reshape(-1).astype(np.int32)
+
+
+def _special_words(v: int, d: int = 1) -> np.ndarray:
+    """A float32 table holding -0.0, inf, a subnormal and a NaN payload."""
+    t = np.asarray(RNG.standard_normal((v, d)), np.float32)
+    t[3] = -0.0
+    t[5] = np.inf
+    t[7] = np.float32(1e-40)
+    t[9] = np.frombuffer(np.uint32(0x7FC0_1234).tobytes(), np.float32)[0]
+    return t
+
+
+LULESH_G7 = list(range(16))                     # delta 41
+LULESH_G3 = [24 * i for i in range(16)]         # delta 8
+
+
+class TestGatherWindow:
+    """The narrow DMA regime's window path: a grid step whose elements
+    lie in ``window_lines`` consecutive lines fetches them with one DMA;
+    any other step fetches one line per element.  Block 1024, as on the
+    chip, so a launch holds several steps."""
+
+    @staticmethod
+    def _gather(table, idx, **kw):
+        return np.asarray(gops.gather_rows_batched(
+            jnp.asarray(table), jnp.asarray(idx), mode="dma", block=1024,
+            **kw))
+
+    @staticmethod
+    def _steps(idx, v, d=1):
+        return gops.window_steps(idx, v, d, mode="dma", block=1024)
+
+    @pytest.mark.parametrize("index,delta", [
+        (list(range(16)), 0), (list(range(16)), 1), (LULESH_G3, 8),
+        (LULESH_G7, 41)])
+    def test_window_path_bit_exact(self, index, delta):
+        idx = _spatter(index, delta, 320)                # 5 steps
+        v = max(int(idx.max()) + 1, 40_000)              # > 32 lines
+        table = _special_words(v)
+        idx[[0, 17, 2000]] = [3, 9, 5]                   # special words
+        idx[4000] = 7
+        out = self._gather(table[None], idx[None])
+        ref = gather_rows_ref(jnp.asarray(table), jnp.asarray(idx))
+        assert out[0].tobytes() == np.asarray(ref).tobytes()
+        assert out[0].tobytes() == table[idx].tobytes()
+        # the special words sit in lines 0 and 15: steps 0 and 1 reach
+        # back to them and leave the window (delta 41 spans 21 lines)
+        hits, steps = self._steps(idx[None], v)
+        assert steps == 5 and hits >= 3
+
+    def test_fallback_on_random_indices(self):
+        v = 1 << 16                                      # 512 lines
+        table = _special_words(v)
+        idx = RNG.integers(0, v, 3000).astype(np.int32)
+        idx[[1, 2]] = [3, 9]
+        out = self._gather(table[None], idx[None])
+        assert out.tobytes() == table[idx][None].tobytes()
+        assert self._steps(idx, v) == (0, 3)
+
+    def test_mixed_launch(self):
+        """One dense member and one random one in one launch: each step
+        takes its own path."""
+        v = 1 << 16
+        table = np.stack([_special_words(v), _special_words(v)])
+        dense = _spatter(LULESH_G3, 8, 256)
+        rand = RNG.integers(0, v, dense.size).astype(np.int32)
+        idx = np.stack([dense, rand])
+        out = self._gather(table, idx)
+        ref = np.stack([table[b][idx[b]] for b in range(2)])
+        assert out.tobytes() == ref.tobytes()
+        assert self._steps(dense, v) == (4, 4)
+        assert self._steps(idx, v) == (4, 8)
+
+    @pytest.mark.parametrize("n", [1, 1000, 2500])
+    def test_ragged_last_step(self, n):
+        """The padded tail repeats the last index, so a ragged last step
+        still fits its window."""
+        v = 1 << 16
+        table = _special_words(v)
+        idx = _spatter(LULESH_G7, 41, -(-n // 16))[:n] + 30_000
+        out = self._gather(table[None], idx[None])
+        assert out.tobytes() == table[idx][None].tobytes()
+        steps = -(-n // 1024)
+        assert self._steps(idx, v) == (steps, steps)
+
+    @pytest.mark.parametrize("v", [8, 1000, 4095])
+    def test_table_smaller_than_the_window(self, v):
+        """Forced into the DMA regime, a table of fewer lines than the
+        window is fetched whole: every step fits."""
+        table = _special_words(max(v, 10))[:v]
+        idx = RNG.integers(0, v, 2100).astype(np.int32)
+        out = self._gather(table[None], idx[None])
+        assert out.tobytes() == table[idx][None].tobytes()
+        assert self._steps(idx, v) == (3, 3)
+
+    @pytest.mark.parametrize("d", [1, 4, 48])
+    def test_counter_matches_device_flags(self, d):
+        """``window_steps`` (numpy) counts the steps whose device-side
+        base is not -1, on Spatter and random indices."""
+        v = 1 << 14
+        table = jnp.zeros((3, v, d), jnp.float32)
+        idx = np.stack([
+            _spatter(LULESH_G7, 41, 300) % v,
+            _spatter(LULESH_G3, 8, 300),
+            RNG.integers(0, v, 4800).astype(np.int32)])
+        _, _, blk, bases, _ = gops._launch_operands(table, jnp.asarray(idx),
+                                                    True, 1024)
+        assert blk == 1024
+        hits, steps = self._steps(idx, v, d)
+        assert (hits, steps) == (int((np.asarray(bases) >= 0).sum()),
+                                 bases.size)
+        assert 0 < hits < steps
+
+    @pytest.mark.parametrize("name", [f"LULESH-G{k}" for k in range(8)])
+    def test_every_lulesh_gather_fits(self, name):
+        """Table 5's LULESH gathers fit the window in every step at block
+        1024 (at 2^14 of their 2^20 i-steps: the fit repeats)."""
+        from repro.core import appdb
+        pat = next(p for p in appdb.LULESH_GATHERS if p.name == name)
+        idx = _spatter(pat.index, pat.delta, 1 << 14)
+        rows = int(idx.max()) + 2                     # + the scratch row
+        hits, steps = gops.window_steps(idx, rows, 1, block=1024,
+                                        mode="dma")
+        assert hits == steps == (1 << 18) // 1024
+
+    def test_no_window_path_outside_the_narrow_dma_regime(self):
+        idx = np.arange(4096, dtype=np.int32)
+        assert gops.window_steps(idx, 1 << 16, 128, mode="dma") == (0, 0)
+        assert gops.window_steps(idx, 4096, 1, mode="vmem") == (0, 0)
+        assert gops.window_steps(idx, 4096, 1) == (0, 0)     # auto: vmem
+        assert gops.window_lines(1024, 8) == 8
+        assert gops.window_lines(1024, 1 << 20) == 32
+
+
 class TestOneHotWords:
     """One-hot stores and gathers move 32-bit words: the Pallas store
     kernel and the onehot backend return every bit pattern unchanged."""

@@ -489,3 +489,39 @@ def test_acceptance_2d_placement_8dev_subprocess():
                        capture_output=True, text=True, timeout=540)
     assert r.returncode == 0, (r.stdout[-1000:], r.stderr[-3000:])
     assert "OK" in r.stdout
+
+
+LANE_WINDOWS = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    import sys
+    sys.path.insert(0, %(src)r)
+    import jax
+    assert len(jax.devices()) == 8, jax.devices()
+    from repro.core import ExecutorCache, SuitePlan, make_pattern, run_suite
+    from repro.kernels.gather_rows import ops as gather_ops
+
+    # a 2^20-element table puts the pallas gather in its DMA regime; at
+    # delta 32 each shard's steps fit their line windows (DESIGN.md §2.4)
+    pat = make_pattern(list(range(16)), kind="gather", delta=32,
+                       count=1 << 15, name="dense")
+    ref = run_suite([pat], backend="xla", runs=1, cache=ExecutorCache(),
+                    digest=True)
+    got = run_suite([pat], backend="pallas", runs=1, cache=ExecutorCache(),
+                    mesh=(1, 8), digest=True)
+    assert got.results[0].out_digest == ref.results[0].out_digest
+    spec = SuitePlan.build([pat]).buckets[0].spec
+    shards = pat.absolute_indices().reshape(8, -1)
+    hits, steps = gather_ops.window_steps(shards, spec.footprint + 1, 1)
+    assert hits == steps > 0, (hits, steps)
+    print("OK")
+    """)
+
+
+def test_lane_sharded_pallas_gather_windows_8dev_subprocess():
+    """Each device of a ``[1, 8]`` lane placement runs the narrow DMA
+    gather on its contiguous shard of lanes, window path included."""
+    r = subprocess.run([sys.executable, "-c", LANE_WINDOWS % {"src": SRC}],
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, (r.stdout[-1000:], r.stderr[-3000:])
+    assert "OK" in r.stdout
