@@ -1165,7 +1165,8 @@ def _assemble_members(spec: BucketSpec, patterns: Sequence[Pattern],
 
     ``spans`` makes each step's span (``core/spans.span`` with the
     launch's sink and ids bound): ``plan.stack`` (the padded buffers and
-    the copies into them), ``plan.draw`` and ``plan.keep_mask`` per
+    the copies into them), ``plan.draw`` (``lanes=``, and ``rows=``: the
+    table rows drawn, the pattern's footprint) and ``plan.keep_mask`` per
     member, and ``plan.to_device`` (the transfer, up to readiness).
     """
     nb = len(patterns)
@@ -1191,7 +1192,8 @@ def _assemble_members(spec: BucketSpec, patterns: Sequence[Pattern],
                 host[2][:, -1] = True   # scratch row's single write (pads)
     real_lanes = []
     for b, p in enumerate(patterns):
-        with spans("plan.draw", lanes=p.count * p.index_len):
+        with spans("plan.draw", lanes=p.count * p.index_len,
+                   rows=p.footprint()):
             src, abs_idx, vals = draw_host_buffers(p, r, seed=seeds[b])
         n = abs_idx.shape[0]
         keep = None

@@ -32,7 +32,8 @@ per launch by the scheduler — plus lifetime counters), ``plan``
 (n_buckets/pad_waste), ``serve`` (scheduler telemetry: ``queued_ms``,
 ``launches``, ``coalesced_launches``, and ``phases_ms`` — the host
 phases of the launches that served the request, ms by span name:
-``plan.draw``, ``plan.keep_mask``, ``plan.stack``, ``plan.to_device``,
+``plan.draw`` (its profiler span carries ``lanes=`` and ``rows=``, the
+table rows drawn), ``plan.keep_mask``, ``plan.stack``, ``plan.to_device``,
 ``plan.warmup``, ``plan.runs``, ``plan.fetch``, ``plan.digest``; a
 coalesced launch's phases are shared by every request it served; null
 on a workers=0 daemon), ``elapsed_s``, and ``request_id`` (the daemon's

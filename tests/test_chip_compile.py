@@ -12,6 +12,7 @@ the CPU here; the ``mosaic`` fixture steers them to the compiled path for
 these tests only.  The persistent compilation cache is off around the
 compiles: entries written for a described chip cannot be read back.
 """
+import dataclasses
 import functools
 
 import jax
@@ -25,6 +26,7 @@ from repro.core import plan as plan_mod
 from repro.core.compat import make_mesh
 from repro.kernels.gather_rows import ops as gops
 from repro.kernels.scatter_rows import ops as sops
+from repro.serve.schema import MAX_PATTERN_LANES
 
 HBM_BYTES = 16 * 2**30           # one v5e chip
 B_NAMES = ("LULESH-G7", "LULESH-G0", "LULESH-S1", "LULESH-S3")
@@ -162,6 +164,32 @@ def test_xla_buckets_at_paper_scale_fit_hbm(mosaic, one_chip):
         total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
                  + ma.temp_size_in_bytes)
         assert total < HBM_BYTES, (key, total)
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_pennant_sparse_buckets_fit_hbm(mosaic, one_chip, backend):
+    # PENNANT-G8..G15 one a request, each at the largest count spatterd
+    # serves: 2,288 to 31,080 lanes on a 2^28 + 1-row (1 GiB) table
+    keys = set()
+    for p in appdb.PENNANT_GATHERS[8:]:
+        p = dataclasses.replace(
+            p, count=(MAX_PATTERN_LANES - p.span) // p.delta + 1)
+        assert p.footprint() <= MAX_PATTERN_LANES < p.footprint() + p.delta
+        for key, builder, avals in plan_mod.enumerate_executables(
+                SuitePlan.build([p]), backend=backend, mode="store"):
+            if key in keys:
+                continue
+            keys.add(key)
+            assert avals[0].shape == (1, MAX_PATTERN_LANES + 1, 1)
+            avals = tuple(_sds(a.shape, a.dtype, one_chip) for a in avals)
+            compiled = builder().lower(*avals).compile()
+            if backend == "pallas":
+                _mosaic_text(compiled)
+            ma = compiled.memory_analysis()
+            total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+                     + ma.temp_size_in_bytes)
+            assert total < HBM_BYTES, (key, total)
+    assert sorted(k.idx_len for k in keys) == [4096, 8192, 16384, 32768]
 
 
 @pytest.mark.parametrize("shape", [(1, 4), (4, 1)])
