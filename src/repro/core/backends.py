@@ -25,9 +25,11 @@ becomes a lane-aligned row here.  R=1 recovers the paper's scalar semantics.
 Store-mode duplicate handling (DESIGN.md §2.1): the paper's parallel scatter
 leaves duplicate-index order unspecified; we pin it to last-write-wins so
 backends are cross-checkable.  The keep mask that implements it is a pure
-function of the (static) index buffer, so it is computed ONCE on the host
-(``keep_last_mask``) at build/plan time and threaded through every store
-scatter as a regular operand — the timed executable contains no sort, no
+function of the (static) pattern, so it is computed on the host at
+build/plan time — in closed form for a ``Pattern``
+(``Pattern.keep_last_mask``, no sort), by ``keep_last_mask`` below for a
+bare index array — and threaded through every store scatter as a
+regular operand — the timed executable contains no sort, no
 dedup, nothing but the access under test (paper §3.5 measurement
 discipline).
 """
@@ -107,8 +109,11 @@ def keep_last_mask(idx: np.ndarray) -> np.ndarray:
     """Host-side last-write-wins keep mask: True at the last occurrence of
     each distinct index value, False elsewhere.
 
-    Pattern indices are static at build/plan time, so this runs ONCE in
-    numpy — never inside a timed executable (DESIGN.md §2.1).
+    For bare index arrays (``_store_keep``'s concrete-index branch): a
+    stable argsort over the lanes.  A ``Pattern`` has the same mask in
+    closed form, ``Pattern.keep_last_mask``, which the engine and planner
+    use.  Either way it runs in numpy, never inside a timed executable
+    (DESIGN.md §2.1).
     """
     idx = np.asarray(idx)
     n = idx.shape[0]

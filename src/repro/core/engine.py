@@ -54,8 +54,9 @@ def make_host_buffers(pattern: Pattern, row_width: int, seed: int = 0):
     flattened (count*index_len,) int32 absolute indices, ``vals`` the
     scatter payload (None for gathers), and ``keep`` the last-write-wins
     keep mask over ``abs_idx`` (None for gathers).  The mask is computed
-    HERE, on the host, once per pattern — static-index preprocessing never
-    enters a timed executable (paper §3.5).  GSEngine builds its device
+    HERE, on the host, in closed form from the pattern
+    (``Pattern.keep_last_mask``, no sort) — static-index preprocessing
+    never enters a timed executable (paper §3.5).  GSEngine builds its device
     buffers from this function and the suite planner (plan.py) from the
     same draws (``draw_host_buffers``) and mask, so batched and
     per-pattern execution see bit-identical inputs.
@@ -63,7 +64,7 @@ def make_host_buffers(pattern: Pattern, row_width: int, seed: int = 0):
     src, abs_idx, vals = draw_host_buffers(pattern, row_width, seed)
     if pattern.kind == "gather":
         return src, abs_idx, None, None
-    return src, abs_idx, vals, B.keep_last_mask(abs_idx)
+    return src, abs_idx, vals, pattern.keep_last_mask()
 
 
 def draw_host_buffers(pattern: Pattern, row_width: int, seed: int = 0):

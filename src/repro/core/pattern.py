@@ -98,6 +98,41 @@ class Pattern:
             raise ValueError("pattern footprint exceeds int32 index range")
         return out.astype(np.int32)
 
+    def keep_last_mask(self) -> np.ndarray:
+        """(count*index_len,) bool last-write-wins keep mask over the
+        flattened ``absolute_indices()``: True where no later lane writes
+        the same row.  Equal to ``backends.keep_last_mask`` of those
+        indices, from the pattern alone and without a sort over the lanes.
+
+        Lane (k, j) writes row ``k*delta + index[j]``.  A later lane of the
+        same op hits it iff a later offset equals ``index[j]``: never kept.
+        A lane of op k + m (m >= 1) hits it iff some offset lies m*delta
+        below ``index[j]``; with m_j the least such m (the nearest smaller
+        offset of j's residue mod delta; 1 when delta is 0) the lane keeps
+        iff k >= count - m_j.  So the mask is one compare of the op number
+        against a threshold per offset, found in O(L log L) on the offsets.
+        """
+        idx = np.asarray(self.index, dtype=np.int64)
+        count, delta = self.count, self.delta
+        # offsets by (residue, value); lexsort is stable, so equal values
+        # stay in buffer order and the last of a run is the last written
+        res = idx % delta if delta else np.zeros_like(idx)
+        order = np.lexsort((idx, res))
+        s, r = idx[order], res[order]
+        later_equal = np.append(s[1:] == s[:-1], False)
+        if delta:
+            first = np.insert(s[1:] != s[:-1], 0, True)
+            same_res = np.insert(r[1:] == r[:-1], 0, False)
+            m = np.insert(np.diff(s), 0, 0) // delta   # at a run's first
+            t_first = np.where(same_res, np.maximum(count - m, 0), 0)
+            t = t_first[first][np.cumsum(first) - 1]   # spread over the run
+        else:
+            t = np.full(s.shape, count - 1)
+        thresh = np.empty_like(t)
+        thresh[order] = np.where(later_equal, count, t)
+        ops = np.arange(count, dtype=np.int64)
+        return (ops[:, None] >= thresh[None, :]).reshape(-1)
+
     def index_array(self) -> np.ndarray:
         return np.asarray(self.index, dtype=np.int32)
 

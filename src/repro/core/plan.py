@@ -77,9 +77,9 @@ tests/test_suite_plan.py on all four backends, and by
 tests/test_sharded_plan.py for the sharded path).
 
 Hot-path hygiene.  Store-mode scatter needs last-write-wins dedup; its
-keep mask is a pure function of the (static) padded index buffer, so
-``_assemble_bucket`` computes it once on the host (backends.keep_last_mask)
-and passes it to the executable as a fourth operand — no sort or dedup
+keep mask is a pure function of the pattern, so ``_assemble_bucket``
+computes it on the host in closed form (``Pattern.keep_last_mask``, no
+sort) and passes it to the executable as a fourth operand — no sort or dedup
 primitive ever appears in a timed executable's jaxpr (asserted by
 tests/test_no_sort.py).  On the pallas backend the batched ops are
 batch-NATIVE kernels (a real grid over pattern-batch x tiles with the
@@ -1154,8 +1154,8 @@ def _assemble_members(spec: BucketSpec, patterns: Sequence[Pattern],
 
     Scatter buckets also carry the (B_pad, N_pad) last-write-wins keep
     mask for store mode: real lanes take the per-pattern mask
-    (``backends.keep_last_mask`` over the member's own indices — real
-    indices never reach the scratch row F_pad, so padding can't change
+    (``Pattern.keep_last_mask``, closed form from the member's pattern,
+    no sort — real indices never reach the scratch row F_pad, so padding can't change
     their dedup), and of the padding lanes — which ALL point at F_pad —
     only each row's final lane keeps, so the single-pass store kernel's
     at-most-one-write-per-row contract holds for every row including
@@ -1199,7 +1199,7 @@ def _assemble_members(spec: BucketSpec, patterns: Sequence[Pattern],
         keep = None
         if store:
             with spans("plan.keep_mask", lanes=n):
-                keep = B.keep_last_mask(abs_idx)
+                keep = p.keep_last_mask()
         real_lanes.append(n)
         with spans("plan.stack"):
             idx_b[b, :n] = abs_idx

@@ -25,7 +25,7 @@ index vector, the second transposed for free by contracting on its
 last dim.  Index vectors are VMEM blocks, never SMEM slices.
 
 Store mode is the same single pass: the host-precomputed last-write-wins
-mask (backends.keep_last_mask, DESIGN.md §2.1) routes dropped lanes out
+mask (Pattern.keep_last_mask, DESIGN.md §2.1) routes dropped lanes out
 of range before launch, so every surviving lane is its element's unique
 write — the kernel initializes each output tile from ``dst`` and
 overwrites exactly the covered elements with the contraction.  A store

@@ -6,7 +6,7 @@ the per-pattern entry points are the B=1 case of the same kernel.  Rows
 narrower than a 128-lane line are scattered element by element into the
 lane-dense flat table; wider rows are padded to a lane multiple
 (DESIGN.md §2.3).  Store mode expects its index buffer pre-deduped on
-the host (dropped lanes routed out of range — backends.keep_last_mask),
+the host (dropped lanes routed out of range — Pattern.keep_last_mask),
 so the kernel is a single pass with no sort and no coverage-count
 launch; it moves 32-bit words, so every stored bit pattern arrives
 unchanged.  Block sizes default to the deterministic per-geometry autotuner

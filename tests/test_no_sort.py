@@ -1,9 +1,10 @@
 """Hot-path hygiene regression tests (ISSUE 3 tentpole, rewired to
 spatterlint in ISSUE 6).
 
-The last-write-wins keep mask for store-mode scatter is computed once on
-the host at build/plan time (backends.keep_last_mask) and threaded
-through as an operand; nothing the engine or planner times may contain a
+The last-write-wins keep mask for store-mode scatter is computed on the
+host at build/plan time (``Pattern.keep_last_mask``: closed form from the
+pattern, no sort; ``backends.keep_last_mask`` for bare index arrays) and
+threaded through as an operand; nothing the engine or planner times may contain a
 ``sort`` primitive, and the pallas backend launches exactly ONE kernel
 per bucket.  These invariants are now owned by the spatterlint rules
 ``no-sort-in-hot-path`` and ``single-pallas-call-per-bucket``
@@ -11,12 +12,15 @@ per bucket.  These invariants are now owned by the spatterlint rules
 rather than a private jaxpr walker, so the test and the lint can never
 disagree about what "no sort in the hot path" means.
 """
+import json
+import pathlib
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.analysis.lint import run_rules, unit_for
-from repro.core import GSEngine, SuitePlan, make_pattern
+from repro.core import GSEngine, Pattern, SuitePlan, make_pattern
 from repro.core import backends as B
 from repro.core.compat import make_mesh
 from repro.core.engine import make_host_buffers
@@ -52,6 +56,97 @@ def test_keep_last_mask_semantics():
     assert B.keep_last_mask(np.zeros((0,), np.int32)).shape == (0,)
     # all duplicates: only the last survives
     assert B.keep_last_mask(np.full(7, 4)).tolist() == [False] * 6 + [True]
+
+
+def _lulesh_scatter(name):
+    cfg = pathlib.Path(__file__).parent.parent / "bench/configs/lulesh.json"
+    (d,) = [d for d in json.loads(cfg.read_text())["patterns"]
+            if d["name"] == name]
+    return [Pattern.from_json(d)]
+
+
+def _sweep(seed, n=600):
+    """n random triples: short buffers with repeats, small deltas/counts,
+    so every overlap case (same op, later op, both, none) occurs."""
+    rng = np.random.default_rng(seed)
+    out = []
+    for _ in range(n):
+        idx = rng.integers(0, 31, int(rng.integers(1, 13)))
+        out.append(Pattern("r", "scatter", tuple(int(i) for i in idx),
+                           int(rng.integers(0, 8)), int(rng.integers(1, 13))))
+    return out
+
+
+def _pat(spec, delta, count):
+    return [make_pattern(spec, kind="scatter", delta=delta, count=count)]
+
+
+_MASK_CASES = {
+    **{f"lulesh-s{i}": (lambda i=i: _lulesh_scatter(f"LULESH-S{i}"))
+       for i in range(4)},
+    "dup": lambda: [DUP],
+    "uniform": lambda: _pat("UNIFORM:8:3", 5, 40),
+    "ms1": lambda: _pat("MS1:8:4:20", 3, 30),
+    "laplacian": lambda: _pat("LAPLACIAN:2:2:10", 7, 50),
+    "broadcast": lambda: _pat("BROADCAST:16:4", 1, 20),
+    "custom-repeated-unsorted": lambda: _pat("CUSTOM:9,2,9,0,5,2,13,7", 3, 25),
+    "delta-0": lambda: _pat("CUSTOM:3,1,3,2", 0, 9),
+    "delta-above-span": lambda: _pat("UNIFORM:8:2", 100, 10),
+    "delta-divides-no-difference": lambda: _pat("CUSTOM:0,3,7", 5, 12),
+    "count-1": lambda: _pat("CUSTOM:4,1,4,0", 2, 1),
+    "len-1": lambda: _pat("CUSTOM:5", 3, 7) + _pat("CUSTOM:5", 0, 7),
+    **{f"sweep-{seed}": (lambda seed=seed: _sweep(seed)) for seed in range(4)},
+}
+
+
+@pytest.mark.parametrize("case", list(_MASK_CASES))
+def test_pattern_keep_last_mask_matches_argsort(case):
+    """The closed form equals the argsort mask over the pattern's own
+    absolute indices, bit for bit."""
+    for p in _MASK_CASES[case]():
+        keep = p.keep_last_mask()
+        ref = B.keep_last_mask(p.absolute_indices().reshape(-1))
+        assert keep.dtype == bool and keep.shape == ref.shape, p
+        assert np.array_equal(keep, ref), p
+
+
+def test_pattern_paths_never_sort(monkeypatch):
+    """Store-mode keep operands from the planner and the engine are the
+    argsort mask's bytes, with the argsort patched to raise: no Pattern
+    path sorts.  Gathers and add mode compute no mask at all."""
+    argsort_mask = B.keep_last_mask
+
+    def boom(idx):
+        raise AssertionError("a Pattern path called backends.keep_last_mask")
+
+    pats = [DUP, Pattern("odd", "scatter", (0, 3, 10, 3), delta=0, count=7),
+            make_pattern("LAPLACIAN:2:2:10", kind="scatter", delta=7,
+                         count=9)]
+    monkeypatch.setattr(B, "keep_last_mask", boom)
+    plan = SuitePlan.build(pats)
+    for bucket in plan.buckets:
+        # padded lanes and scratch batch rows all point at the scratch row:
+        # the argsort over each whole padded row is the operand expected
+        (_, idx_b, _, keep_b), _ = _assemble_bucket(
+            plan, bucket, jnp.float32, 1, 0, batch=len(bucket.members) + 1,
+            lanes=bucket.spec.idx_len + 8)
+        idx_b, keep_b = np.asarray(idx_b), np.asarray(keep_b)
+        for b in range(idx_b.shape[0]):
+            np.testing.assert_array_equal(keep_b[b], argsort_mask(idx_b[b]))
+    for p in pats:
+        _, abs_idx, _, keep = make_host_buffers(p, 1)
+        np.testing.assert_array_equal(keep, argsort_mask(abs_idx))
+
+    monkeypatch.setattr(Pattern, "keep_last_mask", boom)
+    for bucket in plan.buckets:
+        args, _ = _assemble_bucket(plan, bucket, jnp.float32, 1, 0,
+                                   mode="add")
+        assert not np.asarray(args[3]).any()
+    g = make_pattern("UNIFORM:8:2", kind="gather", delta=2, count=32)
+    gplan = SuitePlan.build([g])
+    assert len(_assemble_bucket(gplan, gplan.buckets[0], jnp.float32, 1,
+                                0)[0]) == 2
+    assert make_host_buffers(g, 1)[3] is None
 
 
 def test_make_host_buffers_carries_keep():
